@@ -6,6 +6,8 @@ Three terms per (arch x shape x mesh), all per-chip:
     memory     = HLO_bytes / HBM_bw              (819 GB/s)
     collective = collective_bytes / ICI_bw       (3 links x 50 GB/s)
 
+The peaks come from `launch.mesh.CHIP_PEAKS` for the dry-run's target chip.
+
 HLO_FLOPs/bytes come from the multiplicity-aware HLO analyzer
 (utils/hlo.py) — XLA's cost_analysis counts scan bodies once and is kept in
 the artifacts as ``flops_xla_raw`` for reference.
@@ -25,10 +27,10 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import INPUT_SHAPES, get_config  # noqa: E402
-from repro.launch.mesh import (HBM_BW, ICI_BW_PER_LINK, N_ICI_LINKS,  # noqa: E402
-                               PEAK_FLOPS_BF16)
+from repro.launch.mesh import chip_peaks  # noqa: E402
 
 DRYRUN_DIR = os.environ.get("DRYRUN_DIR", "experiments/dryrun")
+TARGET_KIND = "TPU v5 lite"   # the production meshes of launch.mesh are v5e
 
 
 def active_params(arch: str) -> float:
@@ -81,10 +83,11 @@ def load_artifacts(pattern: str = "*", include_tagged: bool = False):
 
 def roofline_row(art: dict) -> dict:
     chips = art["chips"]
-    compute = art["flops_per_device"] / PEAK_FLOPS_BF16
-    memory = art["bytes_accessed_per_device"] / HBM_BW
+    peaks = chip_peaks(TARGET_KIND)
+    compute = art["flops_per_device"] / peaks["bf16_flops"]
+    memory = art["bytes_accessed_per_device"] / peaks["hbm_bw"]
     coll = (art["collectives"]["total_bytes"]
-            / (ICI_BW_PER_LINK * N_ICI_LINKS))
+            / (peaks["ici_link_bw"] * peaks["ici_links"]))
     terms = {"compute": compute, "memory": memory, "collective": coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(art["arch"], art["shape"])
@@ -92,7 +95,7 @@ def roofline_row(art: dict) -> dict:
     useful = mf / hlo_global if hlo_global else 0.0
     bound = max(terms.values())
     # fraction of roofline: useful-model-compute time / dominant term
-    mf_time = mf / chips / PEAK_FLOPS_BF16
+    mf_time = mf / chips / peaks["bf16_flops"]
     return {
         "arch": art["arch"], "shape": art["shape"], "mesh": art["mesh"],
         "kind": art["kind"],

@@ -44,6 +44,8 @@ def main(argv=None) -> int:
                          "perf records); default: $BENCH_DIR or "
                          "experiments/bench")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     from . import (analysis_bench, autotune_bench, comm_bench,
                    comm_comp, common, detect_bench, faults_bench,

@@ -15,5 +15,6 @@ for extra in ([], ["--multi-pod"]):
     subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", arch, "--shape", shape] + extra,
-        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
         cwd=ROOT, check=True)

@@ -69,7 +69,8 @@ def make_mf_app(cfg: MFConfig) -> PSApp:
     kL, kR = jax.random.split(k_t)
     Lstar = jax.random.normal(kL, (n, cfg.true_rank)) / jnp.sqrt(cfg.true_rank)
     Rstar = jax.random.normal(kR, (cfg.true_rank, m)) / jnp.sqrt(cfg.true_rank)
-    D = Lstar @ Rstar + cfg.noise * jax.random.normal(k_n, (n, m))
+    D = (jnp.matmul(Lstar, Rstar, precision=jax.lax.Precision.HIGHEST)
+         + cfg.noise * jax.random.normal(k_n, (n, m)))
 
     # Observed entries, partitioned by row blocks across workers (the paper
     # partitions data across machines; row blocks keep L-updates local-ish
@@ -108,13 +109,13 @@ def make_mf_app(cfg: MFConfig) -> PSApp:
             (gamma * (e[:, None] * Li - cfg.lam * Rj)).T)
         return _pack(dL, dR), local
 
-    all_i, all_j, all_v = ii.ravel(), jj.ravel(), vv.ravel()
-
     def loss(x, locals_):
-        del locals_
+        # ratings come in as arguments (not closed-over constants), so a
+        # large rating set is not baked into the compiled program
         L, R = unpack(x)
+        all_i, all_j = locals_["ii"].ravel(), locals_["jj"].ravel()
         pred = jnp.sum(L[all_i] * R[:, all_j].T, axis=-1)
-        return jnp.mean(jnp.square(all_v - pred))
+        return jnp.mean(jnp.square(locals_["vv"].ravel() - pred))
 
     local0 = {"ii": ii, "jj": jj, "vv": vv}
     return PSApp(name="matfact", dim=(n + m) * k, n_workers=P,

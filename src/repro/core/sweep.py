@@ -178,13 +178,12 @@ def _family_runner(app: PSApp, n_clocks: int, record_views: bool, devices,
     if n_shards == 1:
         return jax.jit(batched)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(mesh_axis)
-    sharded = jax.jit(shard_map(batched, mesh=mesh,
-                                in_specs=(spec, spec, spec),
-                                out_specs=spec))
+    sharded = jax.jit(jax.shard_map(batched, mesh=mesh,
+                                    in_specs=(spec, spec, spec),
+                                    out_specs=spec, check_vma=False))
 
     def fn(stacked_flat, seeds_flat, idx_flat):
         n = seeds_flat.shape[0]

@@ -32,7 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float(np.finfo(np.float32).min) / 2
 
 
-def supported(q, k, v, _kv_chunk=None) -> bool:
+def supported(q, k, v) -> bool:
     B, Sq, H, Dk = q.shape
     _, Sk, Hkv, _ = k.shape
     return (H % Hkv == 0 and Dk % 8 == 0 and v.shape[-1] % 8 == 0)
@@ -49,14 +49,14 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = qpos_ref[...]                                   # [block_q]
-    k_pos = kpos_ref[...]                                   # [block_k]
-    valid = jnp.broadcast_to((k_pos >= 0)[None, :],
-                             (q_pos.shape[0], k_pos.shape[0]))
+    q_pos = qpos_ref[...]                                   # [block_q, 1]
+    k_pos = kpos_ref[...]                                   # [1, block_k]
+    valid = jnp.broadcast_to(k_pos >= 0,
+                             (q_pos.shape[0], k_pos.shape[1]))
     if causal:
-        valid = valid & (k_pos[None, :] <= q_pos[:, None])
+        valid = valid & (k_pos <= q_pos)
     if window is not None:
-        valid = valid & (k_pos[None, :] > (q_pos[:, None] - window))
+        valid = valid & (k_pos > (q_pos - window))
 
     @pl.when(jnp.any(valid))
     def _compute():
@@ -118,8 +118,12 @@ def flash_attention(q, k, v, *, scale, q_pos, kv_pos, causal=True,
     q_r = q_r.reshape(B * Hkv, rep, Sq_p, Dk)
     k_r = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk_p, Dk)
     v_r = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk_p, Dv)
-    qpos_r = jnp.repeat(q_pos, Hkv, axis=0)
-    kpos_r = jnp.repeat(kv_pos, Hkv, axis=0)
+    # Positions ride as a column [B*Hkv, Sq_p, 1] (q) and a row
+    # [B*Hkv, 1, Sk_p] (kv): Mosaic needs the last two block dims to be
+    # (8, 128)-aligned or whole, which a [1, block] slice of [B*Hkv, S]
+    # is not.
+    qpos_r = jnp.repeat(q_pos, Hkv, axis=0)[:, :, None]
+    kpos_r = jnp.repeat(kv_pos, Hkv, axis=0)[:, None, :]
 
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                window=window, rep=rep, n_kv=n_kv)
@@ -128,8 +132,8 @@ def flash_attention(q, k, v, *, scale, q_pos, kv_pos, causal=True,
         kernel,
         grid=(B * Hkv, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((None, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((None, block_k), lambda b, i, j: (b, j)),
+            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, i, j: (b, 0, j)),
             pl.BlockSpec((None, rep, block_q, Dk),
                          lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((None, block_k, Dk), lambda b, i, j: (b, j, 0)),

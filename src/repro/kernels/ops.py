@@ -1,19 +1,29 @@
 """jit-friendly dispatch wrappers for the Pallas kernels.
 
-On TPU the Pallas implementations run natively; on CPU (this container) the
-wrappers dispatch to the pure-jnp references, and tests exercise the Pallas
-bodies under ``interpret=True``.  Selection can be forced with
-``set_backend("pallas"|"ref")`` (used by kernel tests and benchmarks).
+On TPU the Pallas implementations run natively; on CPU the wrappers
+dispatch to the pure-jnp references, and tests exercise the Pallas bodies
+under ``interpret=True``.  Selection can be forced with
+``set_backend("pallas"|"pallas_interpret"|"ref")`` (kernel tests and
+benchmarks).
+
+On a Pallas backend nothing falls back in silence: the PS kernels pad the
+lane axis ``d`` up to the kernel block (zeros change no sum, inf-norm or
+pack) and slice the result, and a shape no kernel supports raises.
+``kernel_traces()`` counts how often each Pallas kernel was traced into a
+program, which is how a caller proves that a step took the kernel.
 """
 from __future__ import annotations
 
-import functools
+import collections
 
 import jax
+import jax.numpy as jnp
 
 from . import ref
 
 _BACKEND = "auto"
+_LANE = 128                      # kernel block on the lane axis
+_KERNEL_TRACES: collections.Counter = collections.Counter()
 
 # Perf toggles (see EXPERIMENTS.md §Perf): static_causal skips fully-masked
 # causal KV blocks in full-sequence attention (positions are arange there).
@@ -45,61 +55,114 @@ def get_backend() -> str:
     return "pallas" if platform == "tpu" else "ref"
 
 
+def kernel_traces() -> dict:
+    """Pallas kernel name -> number of times it was traced into a program."""
+    return dict(_KERNEL_TRACES)
+
+
+def _pallas(kernel: str) -> bool | None:
+    """``interpret`` flag for ``kernel`` on a Pallas backend (counting the
+    trace), or None on the reference backend."""
+    backend = get_backend()
+    if backend not in ("pallas", "pallas_interpret"):
+        return None
+    _KERNEL_TRACES[kernel] += 1
+    return backend == "pallas_interpret"
+
+
+def _unsupported(kernel: str, shape, need: str):
+    raise ValueError(f"no Pallas {kernel} kernel for shape {tuple(shape)} "
+                     f"({need}); use set_backend('ref') for this shape")
+
+
+def _pad_lanes(x):
+    pad = (-x.shape[-1]) % _LANE
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def attention(q, k, v, *, scale, q_pos, kv_pos, causal=True, window=None,
               kv_chunk=None, q_chunk=None):
-    """Blocked attention; see `ref.attention` for the contract."""
+    """Blocked attention; see `ref.attention` for the contract.
+
+    On a Pallas backend the forward pass is the flash kernel and the
+    backward pass is the VJP of `ref.attention` (a `jax.custom_vjp`)."""
     kv_chunk = kv_chunk or _FLAGS["kv_chunk"]
     q_chunk = q_chunk or _FLAGS["q_chunk"]
-    backend = get_backend()
-    if backend in ("pallas", "pallas_interpret"):
-        from . import flash_attention as fa
-        # The Pallas kernel requires hardware-aligned tiles; fall back for
-        # odd shapes (tests cover both paths).
-        if fa.supported(q, k, v, kv_chunk):
-            return fa.flash_attention(
-                q, k, v, scale=scale, q_pos=q_pos, kv_pos=kv_pos,
-                causal=causal, window=window,
-                interpret=(backend == "pallas_interpret"))
-    return ref.attention(q, k, v, scale=scale, q_pos=q_pos, kv_pos=kv_pos,
-                         causal=causal, window=window, kv_chunk=kv_chunk,
-                         q_chunk=q_chunk,
-                         assume_prefix=_FLAGS["static_causal"])
+    interpret = _pallas("flash_attention")
+    if interpret is None:
+        return ref.attention(q, k, v, scale=scale, q_pos=q_pos,
+                             kv_pos=kv_pos, causal=causal, window=window,
+                             kv_chunk=kv_chunk, q_chunk=q_chunk,
+                             assume_prefix=_FLAGS["static_causal"])
+    from . import flash_attention as fa
+    if not fa.supported(q, k, v):
+        _unsupported("flash_attention", q.shape,
+                     "needs H % Hkv == 0 and head dims % 8 == 0")
+    kw = dict(scale=scale, causal=causal, window=window)
+
+    @jax.custom_vjp
+    def flash(q, k, v, q_pos, kv_pos):
+        return fa.flash_attention(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
+                                  interpret=interpret, **kw)
+
+    def fwd(q, k, v, q_pos, kv_pos):
+        return flash(q, k, v, q_pos, kv_pos), (q, k, v, q_pos, kv_pos)
+
+    def bwd(res, g):
+        q, k, v, q_pos, kv_pos = res
+        _, vjp = jax.vjp(
+            lambda q, k, v: ref.attention(
+                q, k, v, q_pos=q_pos, kv_pos=kv_pos, kv_chunk=kv_chunk,
+                q_chunk=q_chunk, **kw),
+            q, k, v)
+        return (*vjp(g), None, None)
+
+    flash.defvjp(fwd, bwd)
+    return flash(q, k, v, q_pos, kv_pos)
 
 
 def ring_view(base, uring, uclock, cview):
     """PS view materialization; see `ref.ring_view` for the contract."""
-    backend = get_backend()
-    if backend in ("pallas", "pallas_interpret"):
-        from . import ps_view
-        if ps_view.supported(uring):
-            return ps_view.ring_view(
-                base, uring, uclock, cview,
-                interpret=(backend == "pallas_interpret"))
-    return ref.ring_view(base, uring, uclock, cview)
+    interpret = _pallas("ring_view")
+    if interpret is None:
+        return ref.ring_view(base, uring, uclock, cview)
+    from . import ps_view
+    d = uring.shape[-1]
+    uring_p = _pad_lanes(uring)
+    if not ps_view.supported(uring_p):
+        _unsupported("ring_view", uring.shape, "needs P <= 128, W <= 64")
+    return ps_view.ring_view(_pad_lanes(base), uring_p, uclock, cview,
+                             interpret=interpret)[:, :d]
 
 
 def vap_suffix_norms(uring, uclock, c):
     """VAP suffix-aggregate inf-norms; see `ref.vap_suffix_norms`."""
-    backend = get_backend()
-    if backend in ("pallas", "pallas_interpret"):
-        from . import ps_view
-        if ps_view.supported(uring):
-            return ps_view.vap_suffix_norms(
-                uring, uclock, c,
-                interpret=(backend == "pallas_interpret"))
-    return ref.vap_suffix_norms(uring, uclock, c)
+    interpret = _pallas("vap_suffix_norms")
+    if interpret is None:
+        return ref.vap_suffix_norms(uring, uclock, c)
+    from . import ps_view
+    uring_p = _pad_lanes(uring)
+    if not ps_view.supported(uring_p):
+        _unsupported("vap_suffix_norms", uring.shape,
+                     "needs P <= 128, W <= 64")
+    return ps_view.vap_suffix_norms(uring_p, uclock, c, interpret=interpret)
 
 
 def delta_pack(delta, thresh, scale, quant: str = "f32"):
     """Comm-substrate compression pack; see `ref.delta_pack`."""
-    backend = get_backend()
-    if backend in ("pallas", "pallas_interpret"):
-        from . import delta_pack as dp
-        if dp.supported(delta):
-            return dp.delta_pack(
-                delta, thresh, scale, quant,
-                interpret=(backend == "pallas_interpret"))
-    return ref.delta_pack(delta, thresh, scale, quant)
+    interpret = _pallas("delta_pack")
+    if interpret is None:
+        return ref.delta_pack(delta, thresh, scale, quant)
+    from . import delta_pack as dp
+    d = delta.shape[-1]
+    delta_p = _pad_lanes(delta)
+    if not dp.supported(delta_p):
+        _unsupported("delta_pack", delta.shape, "needs P <= 128")
+    wire, res = dp.delta_pack(delta_p, thresh, scale, quant,
+                              interpret=interpret)
+    return wire[:, :d], res[:, :d]
 
 
 def mf_sgd_block(L, R, D, mask, gamma, lam):

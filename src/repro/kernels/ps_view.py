@@ -40,20 +40,23 @@ def supported(uring, block_d: int = 128) -> bool:
 
 def _ring_view_kernel(uclock_ref, cview_ref, base_ref, uring_ref, out_ref):
     W = uring_ref.shape[0]
-    cview = cview_ref[...]                                   # [P, P] int32
+    cview = cview_ref[...]                                   # [R, P] int32
     acc = jnp.broadcast_to(base_ref[...], out_ref.shape).astype(jnp.float32)
     for w in range(W):                                       # static unroll
         uc = uclock_ref[w, 0]
-        vis = (cview >= uc) & (uc > RING_INVALID)            # [P(r), P(q)]
+        vis = (cview >= uc) & (uc > RING_INVALID)            # [R(r), P(q)]
         acc = acc + jnp.dot(vis.astype(jnp.float32), uring_ref[w],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     out_ref[...] = acc
 
 
 def ring_view(base, uring, uclock, cview, *, block_d: int = 128,
               interpret: bool = False):
-    """Contract identical to `ref.ring_view`."""
+    """Contract identical to `ref.ring_view`: ``cview`` is [R, P] for R
+    readers (a worker shard's rows, R <= P) of P producers."""
     W, P, d = uring.shape
+    R = cview.shape[0]
     block_d = min(block_d, d)
     assert d % block_d == 0
     return pl.pallas_call(
@@ -61,12 +64,12 @@ def ring_view(base, uring, uclock, cview, *, block_d: int = 128,
         grid=(d // block_d,),
         in_specs=[
             pl.BlockSpec((W, 1), lambda i: (0, 0)),           # uclock
-            pl.BlockSpec((P, P), lambda i: (0, 0)),           # cview
+            pl.BlockSpec((R, P), lambda i: (0, 0)),           # cview
             pl.BlockSpec((1, block_d), lambda i: (0, i)),     # base
             pl.BlockSpec((W, P, block_d), lambda i: (0, 0, i)),
         ],
-        out_specs=pl.BlockSpec((P, block_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((P, d), jnp.float32),
+        out_specs=pl.BlockSpec((R, block_d), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
         interpret=interpret,
     )(uclock.reshape(W, 1), cview, base.reshape(1, d),
       uring.astype(jnp.float32))

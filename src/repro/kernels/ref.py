@@ -166,8 +166,11 @@ def ring_view(base, uring, uclock, cview):
     """
     valid = uclock > RING_INVALID
     vis = (uclock[None, :, None] <= cview[:, None, :]) & valid[None, :, None]
+    # HIGHEST keeps the f32 ring out of the TPU's default bf16 pass; the
+    # CPU computes f32 either way.
     return base[None, :] + jnp.einsum("rwq,wqd->rd", vis.astype(uring.dtype),
-                                      uring)
+                                      uring,
+                                      precision=jax.lax.Precision.HIGHEST)
 
 
 def delta_pack(delta, thresh, scale, quant: str = "f32"):
@@ -216,7 +219,8 @@ def vap_suffix_norms(uring, uclock, c):
     W, P, _ = uring.shape
     ks = jnp.arange(1, W + 1, dtype=uclock.dtype)
     sel = (uclock[None, :] == (c - ks)[:, None]).astype(uring.dtype)  # [k,w]
-    contrib = jnp.einsum("kw,wqd->kqd", sel, uring)
+    contrib = jnp.einsum("kw,wqd->kqd", sel, uring,
+                         precision=jax.lax.Precision.HIGHEST)
     suffix = jnp.cumsum(contrib, axis=0)
     norms = jnp.max(jnp.abs(suffix), axis=-1)                         # [W,P]
     return jnp.concatenate([jnp.zeros((1, P), norms.dtype), norms], axis=0)

@@ -1,7 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import: jax locks the device
-# count at first initialization (see MULTI-POD DRY-RUN spec).
+os.environ["JAX_PLATFORMS"] = "cpu"   # the 512 devices are host devices:
+# never open an attached chip.  These lines MUST run before any other
+# import: jax locks the platform and device count at first initialization.
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402

@@ -127,8 +127,21 @@ def make_ps_mesh(data: int | None = None, model: int | None = None,
                 ("data", "model"))
 
 
-# TPU v5e hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW_PER_LINK = 50e9        # bytes/s per link (~3 links usable per axis
-N_ICI_LINKS = 3               # on a 2D torus slice; documented assumption)
+# Published per-chip peaks (roofline denominators), keyed by JAX's
+# ``device_kind``.  Source: Google Cloud documentation, "TPU v5e" (197
+# TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI).
+# The ICI total is 4 links x 50 GB/s; ``ici_links`` = 3 usable per mesh
+# axis on a 2-D torus slice is this repo's assumption, not published.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9, "ici_links": 3},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an error."""
+    if device_kind not in CHIP_PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to CHIP_PEAKS")
+    return CHIP_PEAKS[device_kind]

@@ -26,6 +26,7 @@ from ..optim.optimizers import adamw, cosine_schedule
 from ..psdist.grad_sync import GradSync
 from ..train.loop import train
 from ..train.state import init_state, make_accum_train_step, make_train_step
+from .cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     model = build_model(cfg)

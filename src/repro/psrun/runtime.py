@@ -67,7 +67,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P_
 
 from ..comm import substrate as comm
@@ -613,11 +612,11 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         # post-reduce the accumulators are replicated on every shard
         out_specs["obs"] = jax.tree_util.tree_map(
             lambda _: P_(), obsm.device_init(P, obs.n_buckets))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
-        check_rep=False)
+        check_vma=False)
 
     def run(state: PSState, cfg, sched, flt):
         args = (cfg, state.clock, state.base, state.uring,

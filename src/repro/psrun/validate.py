@@ -40,6 +40,8 @@ from .runtime import PSRuntime
 
 TRACE_FIELDS = ("loss_ref", "loss_view", "staleness", "forced", "delivered",
                 "u_l2", "intransit_inf", "ship_floats", "live", "x_final")
+# the integer decisions: exact under every model and on every backend
+INT_FIELDS = ("staleness", "forced", "delivered", "live")
 
 # Float drift budget for VAP under multi-device compilation (see module
 # doc), asserted in ulp units so it stays scale-free.  Measured drift on
@@ -131,10 +133,13 @@ def cross_validate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
 
     Returns a dict with ``ok`` plus the per-model evidence.  BSP/SSP/ESSP
     compare bit-for-bit against ``simulate`` (SSP/ESSP additionally check
-    the (two-tier) staleness bound); VAP checks the value bound, exact
-    decisions, and the ulp drift budget.  ``return_trace=True`` adds the
-    runtime's `Trace` under ``"trace"`` so callers layering further checks
-    (``pods.validate``) don't re-execute the run.  ``schedule`` (a
+    the (two-tier) staleness bound) and also report ``ints_exact`` (the
+    `INT_FIELDS` decisions) and ``max_ulp`` per field, so a float drift
+    can be told apart from a decision that differs; VAP checks the value
+    bound, exact decisions, and the ulp drift budget.
+    ``return_trace=True`` adds the runtime's `Trace` under ``"trace"`` so
+    callers layering further checks (``pods.validate``) don't re-execute
+    the run.  ``schedule`` (a
     `core.delays.ChurnSchedule`) runs *both* engines under the same fleet
     churn — the bit-identity contract covers the survivor set too.
     ``faults`` (a `comm.wire.WireFaults`) runs both engines over the same
@@ -149,16 +154,28 @@ def cross_validate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
     out: dict = {"model": cfg.model}
 
     def _oracle():
+        import dataclasses
+
         import jax
+        # The app's data enter as arguments, as they do in the runtime.
+        # Closed over, they become constants that XLA may fold, and the
+        # TPU then reduces the loss in another order (LDA: one [P*ntok]
+        # reduce instead of [P, ntok]) — a few ulp in the loss.
         return jax.jit(
-            lambda sd: simulate(app, cfg, n_clocks, seed=sd,
-                                schedule=schedule,
-                                faults=faults))(np.uint32(seed))
+            lambda sd, x0, local0: simulate(
+                dataclasses.replace(app, x0=x0, local0=local0), cfg,
+                n_clocks, seed=sd, schedule=schedule, faults=faults))(
+            np.uint32(seed), app.x0, app.local0)
 
     if cfg.model in ("bsp", "ssp", "essp"):
         want = _oracle()
         diffs = trace_max_diff(tr, want)
         out["max_diff"] = diffs
+        out["max_ulp"] = trace_max_ulp(tr, want)
+        out["ints_exact"] = all(
+            np.array_equal(np.asarray(getattr(tr, name)),
+                           np.asarray(getattr(want, name)))
+            for name in INT_FIELDS)
         out["ok"] = all(v == 0.0 for v in diffs.values())
         if cfg.model in ("ssp", "essp") and faults is None:
             chk = check_staleness_bound(tr, cfg)

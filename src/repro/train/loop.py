@@ -12,7 +12,10 @@ def train(train_step, state, batches: Iterable, n_steps: int,
           log_every: int = 10, checkpoint_fn: Callable | None = None,
           checkpoint_every: int = 0, log_fn=print):
     """Run the compiled train step over a batch iterator."""
-    step_fn = jax.jit(train_step) if not hasattr(train_step, "lower") else train_step
+    # the state is donated: old and new params/optimizer state never
+    # have to fit on the device together
+    step_fn = (train_step if hasattr(train_step, "lower")
+               else jax.jit(train_step, donate_argnums=0))
     history = []
     t0 = time.time()
     tokens_seen = 0

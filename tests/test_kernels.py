@@ -236,3 +236,99 @@ def test_flash_attention_decode_ring_buffer_layout():
                             interpret=True)
     np.testing.assert_allclose(np.asarray(got_w), np.asarray(want_w),
                                atol=3e-5)
+
+
+def test_attention_custom_vjp_matches_ref_grad():
+    """On a Pallas backend the attention gradient is the reference's VJP
+    around the flash forward: values and gradients match the ref path."""
+    q, k, v, qp, kp = _attn_inputs(2, 64, 64, 4, 2, 16, 16, jnp.float32)
+
+    def loss(q, k, v):
+        out = ops.attention(q, k, v, scale=0.25, q_pos=qp, kv_pos=kp)
+        return jnp.sum(jnp.sin(out))
+
+    try:
+        ops.set_backend("ref")
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        ops.set_backend("pallas_interpret")
+        got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    finally:
+        ops.set_backend("auto")
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4)
+
+
+@pytest.mark.parametrize("d", [200, 128, 300])
+def test_ps_ops_pad_unaligned_width(d):
+    """The Pallas branch pads the lane axis to the kernel block and slices
+    back, so every width takes the kernel and matches the reference."""
+    from repro.comm import substrate as comm
+    rng = np.random.default_rng(d)
+    W, P, R, c = 5, 8, 4, 7
+    uring = jnp.asarray(rng.normal(size=(W, P, d)).astype(np.float32))
+    uclock = jnp.asarray([6, 5, 4, 3, -(10**9)], jnp.int32)
+    cview = jnp.asarray(rng.integers(2, c, size=(R, P)).astype(np.int32))
+    base = jnp.asarray(rng.normal(size=(d,)).astype(np.float32))
+    delta = uring[0]
+    thresh = comm.row_threshold(delta, 0.3)
+    scale = comm.quant_scale(delta, "f32")
+    outs = {}
+    for backend in ("ref", "pallas_interpret"):
+        ops.set_backend(backend)
+        try:
+            outs[backend] = (ops.ring_view(base, uring, uclock, cview),
+                             ops.vap_suffix_norms(uring, uclock, jnp.int32(c)),
+                             *ops.delta_pack(delta, thresh, scale, "f32"))
+        finally:
+            ops.set_backend("auto")
+    for g, w in zip(outs["pallas_interpret"], outs["ref"], strict=True):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_traces_count_pallas_programs():
+    """`ops.kernel_traces()` counts each Pallas kernel traced into a
+    program (how a caller proves a step took the kernel); the reference
+    backend counts nothing."""
+    W, P, d = 3, 4, 128
+    uring = jnp.ones((W, P, d))
+    uclock = jnp.asarray([2, 1, 0], jnp.int32)
+    cview = jnp.full((P, P), 2, jnp.int32)
+    counts = {}
+    for backend in ("ref", "pallas_interpret"):
+        ops.set_backend(backend)
+        try:
+            before = ops.kernel_traces().get("ring_view", 0)
+            jax.jit(lambda b: ops.ring_view(b, uring, uclock, cview))(
+                jnp.zeros((d,)))
+            counts[backend] = ops.kernel_traces().get("ring_view", 0) - before
+        finally:
+            ops.set_backend("auto")
+    assert counts == {"ref": 0, "pallas_interpret": 1}
+
+
+@pytest.mark.parametrize("kernel", ["ring_view", "vap_suffix_norms",
+                                    "delta_pack", "attention"])
+def test_pallas_backend_rejects_unsupported_shape(kernel):
+    """No silent fallback: a shape no kernel supports raises."""
+    W, P, d = 65, 4, 128                          # W > 64: no ring kernel
+    uring = jnp.zeros((W, P, d))
+    uclock = jnp.zeros((W,), jnp.int32)
+    calls = {
+        "ring_view": lambda: ops.ring_view(jnp.zeros((d,)), uring, uclock,
+                                           jnp.zeros((P, P), jnp.int32)),
+        "vap_suffix_norms": lambda: ops.vap_suffix_norms(uring, uclock, 3),
+        "delta_pack": lambda: ops.delta_pack(jnp.zeros((129, d)),
+                                             jnp.zeros(129), jnp.ones(129)),
+        "attention": lambda: ops.attention(
+            *_attn_inputs(1, 16, 16, 2, 2, 12, 12, jnp.float32)[:3],
+            scale=0.3, q_pos=jnp.zeros((1, 16), jnp.int32),
+            kv_pos=jnp.zeros((1, 16), jnp.int32)),   # head dim 12 % 8 != 0
+    }
+    ops.set_backend("pallas")
+    try:
+        with pytest.raises(ValueError, match="no Pallas"):
+            calls[kernel]()
+    finally:
+        ops.set_backend("auto")

@@ -83,6 +83,36 @@ def test_roofline_row_math():
     assert 4.8e16 < r["model_flops"] < 5.4e16
 
 
+def test_chip_peaks_unknown_kind_raises():
+    from repro.launch.mesh import chip_peaks
+    assert chip_peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("TPU v9 imaginary")
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placement(monkeypatch, env):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache sits at the fixed, git-ignored <repo>/.jax_cache."""
+    from repro.launch import cache
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        got = cache.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    if env is None:
+        assert got == now == str(cache.REPO_ROOT / ".jax_cache")
+        assert (cache.REPO_ROOT / "chip_smoke.py").exists()
+        assert ".jax_cache/" in (cache.REPO_ROOT / ".gitignore").read_text()
+    else:
+        assert got == env and now == was
+
+
 def test_active_params_moe():
     from benchmarks.roofline import active_params
     full = active_params("llama3-8b")
