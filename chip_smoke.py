@@ -30,6 +30,7 @@ import argparse
 import gc
 import json
 import math
+import re
 import sys
 import time
 from importlib import metadata
@@ -87,11 +88,23 @@ def peak_bytes():
     return stats.get("peak_bytes_in_use", "not reported")
 
 
-def kernel_delta(before):
-    from repro.kernels import ops
-    now = ops.kernel_traces()
-    return {k: now[k] - before.get(k, 0) for k in now
-            if now[k] != before.get(k, 0)}
+KERNELS = ("ring_view", "vap_suffix_norms", "delta_pack", "flash_attention")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a component of the op_name path: the kernel's name, inside the
+# transformations applied to it (``jvp(flash_attention)``)
+KERNEL = re.compile(r"(?:\w+\()*(" + "|".join(KERNELS) + r")\)*")
+
+
+def kernels_in(lowered):
+    """Kernel name -> ops of the compiled program under that name: each
+    Pallas kernel's ``pallas_call`` carries ``name=``, which lands in the
+    ``op_name`` of the ops it compiles to."""
+    counts = {}
+    for op_name in OP_NAME.findall(lowered.compile().as_text()):
+        for k in {m.group(1) for part in op_name.split("/")
+                  if (m := KERNEL.fullmatch(part))}:
+            counts[k] = counts.get(k, 0) + 1
+    return counts
 
 
 # --------------------------------------------------------------------------
@@ -128,14 +141,10 @@ def check_oracle(phase, name, res):
     require(res.get("violations", 0) == 0, f"{name}: staleness bound broken")
 
 
-def run_counted(rt, app, cfg, n_clocks):
-    """One runtime run, returning the kernels traced into its program."""
-    import jax
-
-    from repro.kernels import ops
-    before = ops.kernel_traces()
-    jax.block_until_ready(rt.run(app, cfg, n_clocks))
-    return kernel_delta(before)
+def runtime_kernels(rt, app, cfg, n_clocks):
+    """The kernels in the program ``rt.run(app, cfg, n_clocks)`` runs."""
+    fn = rt.run_fn(app, cfg, n_clocks)
+    return kernels_in(fn.lower(fn.init_state(0), cfg))
 
 
 def phase_oracle():
@@ -152,7 +161,7 @@ def phase_oracle():
              ("lda bsp", lda, bsp()), ("lda essp(3)", lda, essp(3)),
              ("mf compressed int8 wire", mf, wire)]
     for name, app, cfg in cases:
-        kernels = run_counted(rt, app, cfg, ORACLE_CLOCKS)
+        kernels = runtime_kernels(rt, app, cfg, ORACLE_CLOCKS)
         say("oracle", f"{name}: runtime kernels", **kernels)
         require(kernels.get("ring_view", 0) > 0,
                 f"{name}: ring_view kernel not in the runtime's program")
@@ -169,7 +178,6 @@ def phase_mf_big():
 
     from repro.apps.matfact import MFConfig, make_mf_app
     from repro.core import essp
-    from repro.kernels import ops
     from repro.psrun import PSRuntime, default_mesh
     cfg_mf = MFConfig(**MF_BIG)
     app = make_mf_app(cfg_mf)
@@ -185,12 +193,12 @@ def phase_mf_big():
     say("mf", "bytes", table=d * 4, ring=W * P * d * 4,
         ratings=sum(x.nbytes for x in jax.tree.leaves(app.local0)))
 
-    before, c0 = ops.kernel_traces(), _COMPILE["secs"]
+    c0 = _COMPILE["secs"]
     t0 = time.perf_counter()
     tr = jax.block_until_ready(rt.run(app, cfg, MF_BIG_CLOCKS))
     first = time.perf_counter() - t0
     compile_s = _COMPILE["secs"] - c0
-    kernels = kernel_delta(before)
+    kernels = runtime_kernels(rt, app, cfg, MF_BIG_CLOCKS)
     t0 = time.perf_counter()
     jax.block_until_ready(rt.run(app, cfg, MF_BIG_CLOCKS))
     steady = time.perf_counter() - t0
@@ -248,23 +256,49 @@ def check_attention():
             "flash attention disagrees with ref.attention")
 
 
+def train_step_kernels():
+    """The kernels in the train step ``repro.launch.train`` compiles for
+    phase 4's arguments, lowered from the state's shapes alone (the same
+    model, optimizer, gradient sync and batch as the launcher's)."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.data.synthetic import TokenGenConfig, modality_stub, \
+        token_batches
+    from repro.models.registry import build_model
+    from repro.optim.optimizers import adamw, cosine_schedule
+    from repro.psdist.grad_sync import GradSync
+    from repro.train.state import init_state, make_train_step
+    cfg = get_config("qwen3-0.6b")
+    model = build_model(cfg)
+    opt = adamw(cosine_schedule(3e-3, TRAIN_STEPS // 10, TRAIN_STEPS))
+    sync = GradSync("essp", 0, 8)
+    state = jax.eval_shape(
+        lambda: init_state(model, opt, sync, jax.random.PRNGKey(0)))
+    batch = next(iter(token_batches(
+        TokenGenConfig(vocab_size=cfg.vocab_size, seq_len=TRAIN_SEQ,
+                       batch=TRAIN_BATCH, seed=0),
+        1, extra=modality_stub(cfg, TRAIN_BATCH))))
+    step = jax.jit(make_train_step(model, opt, sync), donate_argnums=0)
+    return kernels_in(step.lower(state, batch))
+
+
 def phase_train():
-    from repro.kernels import ops
     from repro.launch.train import main as train_main
     gc.collect()                  # free the MF phase's device buffers first
     check_attention()
-    before, c0 = ops.kernel_traces(), _COMPILE["secs"]
+    c0 = _COMPILE["secs"]
     hist = train_main(["--arch", "qwen3-0.6b", "--full",
                        "--consistency", "essp", "--buckets", "8",
                        "--steps", str(TRAIN_STEPS),
                        "--batch", str(TRAIN_BATCH), "--seq", str(TRAIN_SEQ),
                        "--log-every", "1"])
-    kernels = kernel_delta(before)
+    compile_s = _COMPILE["secs"] - c0
+    kernels = train_step_kernels()
     losses = [h["loss"] for h in hist]
     say("train", "qwen3-0.6b full width", steps=len(losses),
         tokens_per_step=TRAIN_BATCH * TRAIN_SEQ, losses=losses,
-        compile_s=_COMPILE["secs"] - c0, peak_bytes_in_use=peak_bytes(),
-        **kernels)
+        compile_s=compile_s, peak_bytes_in_use=peak_bytes(), **kernels)
     require(len(losses) == TRAIN_STEPS, "trainer took too few steps")
     require(all(math.isfinite(x) for x in losses), "trainer loss not finite")
     require(kernels.get("flash_attention", 0) > 0,

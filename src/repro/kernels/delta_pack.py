@@ -75,6 +75,7 @@ def delta_pack(delta, thresh, scale, quant: str = "f32", *,
         out_shape=[jax.ShapeDtypeStruct((P, d), jnp.float32),
                    jax.ShapeDtypeStruct((P, d), jnp.float32)],
         interpret=interpret,
+        name="delta_pack",
     )(thresh.reshape(P, 1).astype(jnp.float32),
       scale.reshape(P, 1).astype(jnp.float32),
       delta.astype(jnp.float32))

@@ -148,6 +148,7 @@ def flash_attention(q, k, v, *, scale, q_pos, kv_pos, causal=True,
             pltpu.VMEM((rep * block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qpos_r, kpos_r, q_r, k_r, v_r)
 
     out = out.reshape(B, Hkv, rep, Sq_p, Dv)[:, :, :, :Sq]

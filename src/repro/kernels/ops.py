@@ -9,12 +9,11 @@ benchmarks).
 On a Pallas backend nothing falls back in silence: the PS kernels pad the
 lane axis ``d`` up to the kernel block (zeros change no sum, inf-norm or
 pack) and slice the result, and a shape no kernel supports raises.
-``kernel_traces()`` counts how often each Pallas kernel was traced into a
-program, which is how a caller proves that a step took the kernel.
+The kernels' ``pallas_call``s carry ``name=`` (``ring_view``,
+``vap_suffix_norms``, ``delta_pack``, ``flash_attention``): a compiled
+program that took one holds its name in its ops' ``op_name``.
 """
 from __future__ import annotations
-
-import collections
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +22,6 @@ from . import ref
 
 _BACKEND = "auto"
 _LANE = 128                      # kernel block on the lane axis
-_KERNEL_TRACES: collections.Counter = collections.Counter()
 
 # Perf toggles (see EXPERIMENTS.md §Perf): static_causal skips fully-masked
 # causal KV blocks in full-sequence attention (positions are arange there).
@@ -55,18 +53,12 @@ def get_backend() -> str:
     return "pallas" if platform == "tpu" else "ref"
 
 
-def kernel_traces() -> dict:
-    """Pallas kernel name -> number of times it was traced into a program."""
-    return dict(_KERNEL_TRACES)
-
-
-def _pallas(kernel: str) -> bool | None:
-    """``interpret`` flag for ``kernel`` on a Pallas backend (counting the
-    trace), or None on the reference backend."""
+def _pallas() -> bool | None:
+    """``interpret`` flag for a kernel on a Pallas backend, or None on the
+    reference backend."""
     backend = get_backend()
     if backend not in ("pallas", "pallas_interpret"):
         return None
-    _KERNEL_TRACES[kernel] += 1
     return backend == "pallas_interpret"
 
 
@@ -90,7 +82,7 @@ def attention(q, k, v, *, scale, q_pos, kv_pos, causal=True, window=None,
     backward pass is the VJP of `ref.attention` (a `jax.custom_vjp`)."""
     kv_chunk = kv_chunk or _FLAGS["kv_chunk"]
     q_chunk = q_chunk or _FLAGS["q_chunk"]
-    interpret = _pallas("flash_attention")
+    interpret = _pallas()
     if interpret is None:
         return ref.attention(q, k, v, scale=scale, q_pos=q_pos,
                              kv_pos=kv_pos, causal=causal, window=window,
@@ -125,7 +117,7 @@ def attention(q, k, v, *, scale, q_pos, kv_pos, causal=True, window=None,
 
 def ring_view(base, uring, uclock, cview):
     """PS view materialization; see `ref.ring_view` for the contract."""
-    interpret = _pallas("ring_view")
+    interpret = _pallas()
     if interpret is None:
         return ref.ring_view(base, uring, uclock, cview)
     from . import ps_view
@@ -139,7 +131,7 @@ def ring_view(base, uring, uclock, cview):
 
 def vap_suffix_norms(uring, uclock, c):
     """VAP suffix-aggregate inf-norms; see `ref.vap_suffix_norms`."""
-    interpret = _pallas("vap_suffix_norms")
+    interpret = _pallas()
     if interpret is None:
         return ref.vap_suffix_norms(uring, uclock, c)
     from . import ps_view
@@ -152,7 +144,7 @@ def vap_suffix_norms(uring, uclock, c):
 
 def delta_pack(delta, thresh, scale, quant: str = "f32"):
     """Comm-substrate compression pack; see `ref.delta_pack`."""
-    interpret = _pallas("delta_pack")
+    interpret = _pallas()
     if interpret is None:
         return ref.delta_pack(delta, thresh, scale, quant)
     from . import delta_pack as dp

@@ -71,6 +71,7 @@ def ring_view(base, uring, uclock, cview, *, block_d: int = 128,
         out_specs=pl.BlockSpec((R, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
         interpret=interpret,
+        name="ring_view",
     )(uclock.reshape(W, 1), cview, base.reshape(1, d),
       uring.astype(jnp.float32))
 
@@ -110,5 +111,6 @@ def vap_suffix_norms(uring, uclock, c, *, block_d: int = 128,
         out_specs=pl.BlockSpec((W + 1, P), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((W + 1, P), jnp.float32),
         interpret=interpret,
+        name="vap_suffix_norms",
     )(uclock.reshape(W, 1), jnp.asarray(c, jnp.int32).reshape(1, 1),
       uring.astype(jnp.float32))

@@ -42,7 +42,14 @@ Per clock, inside ``shard_map`` (collectives annotated):
 5. the end-of-clock delivery matrix (the synthetic network model shared
    with the simulator — `core.delays`, two-tier under ``cfg.n_pods > 1``)
    advances ``cview`` eagerly for ESSP/async/VAP; SSP ignores pushes
-   (pull-based).
+   (pull-based);
+6. the clock's record (losses over the full data, staleness, deliveries)
+   becomes one row of the `Trace`.
+
+Each step runs under a ``jax.named_scope``: ``psrun.enforce`` (1, with
+the suffix norms), ``psrun.view`` (2), ``psrun.update`` (3),
+``psrun.push`` (4 and 5) and ``psrun.record`` (6), so a profiler trace
+can charge every device op of the clock to its stage.
 
 RNG and arithmetic mirror ``core.ps.simulate`` *exactly* (same key splits,
 same per-coordinate reduction orders), which is what makes the simulator an
@@ -172,7 +179,10 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
     The callable also exposes the state-carrying entry points
     ``fn.init_state(seed) -> PSState`` and ``fn.run_from(state, cfg,
     schedule) -> (Trace, PSState)``; ``fn(seed, cfg)`` is exactly
-    ``fn.run_from(fn.init_state(seed), cfg)[0]``.  Schedules index by
+    ``fn.run_from(fn.init_state(seed), cfg)[0]``.  ``fn.lower(state, cfg)``
+    lowers ``run_from``'s program without running it.  Each call runs
+    under the profiler span ``psrun.dispatch`` (a no-op when no profiler
+    records).  Schedules index by
     *absolute* clock, so a resumed segment reads the same slice the
     uninterrupted run would.
 
@@ -261,6 +271,10 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         vmapped_update = jax.vmap(app.worker_update,
                                   in_axes=(0, 0, 0, None, 0))
 
+        # Each stage of the clock runs under a named scope ``psrun.<stage>``
+        # (enforce, view, update, push, record).  The names reach only the
+        # HLO ``op_name`` metadata, which a profiler trace keeps per device
+        # op (``chipbench/stages.py`` reads it); the arithmetic is unchanged.
         def step(carry, c):
             if obs_enabled:
                 *carry, oacc = carry
@@ -268,273 +282,278 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 base, uring, uclock, cview, local, rng, cst = carry
             else:
                 base, uring, uclock, cview, local, rng = carry
-            rng, k_upd, k_net = jax.random.split(rng, 3)
+            with jax.named_scope("psrun.enforce"):
+                rng, k_upd, k_net = jax.random.split(rng, 3)
 
-            if churned:
-                live_now, died = churn_live(sched, c)     # [P], [P]
-                live_l = jax.lax.dynamic_slice_in_dim(
-                    live_now, rows0, Pl)                  # local reader rows
-                rates = churn_rates(cfg, sched, P, c)
-                if sched.drop_inflight:
-                    # drop policy: mirror the oracle — a dying worker's
-                    # in-flight ring rows (and unshipped comm rows) zero
-                    # out the clock it dies.
-                    keep = ~died
-                    uring = jnp.where(keep[None, :, None], uring, 0.0)
-                    if wired:
-                        cst = dict(cst,
-                                   acc=jnp.where(keep[:, None],
-                                                 cst["acc"], 0.0),
-                                   res=jnp.where(keep[:, None],
-                                                 cst["res"], 0.0),
-                                   xring=jnp.where(keep[None, :, None],
-                                                   cst["xring"], 0.0))
+                if churned:
+                    live_now, died = churn_live(sched, c)     # [P], [P]
+                    live_l = jax.lax.dynamic_slice_in_dim(
+                        live_now, rows0, Pl)                  # local reader rows
+                    rates = churn_rates(cfg, sched, P, c)
+                    if sched.drop_inflight:
+                        # drop policy: mirror the oracle — a dying worker's
+                        # in-flight ring rows (and unshipped comm rows) zero
+                        # out the clock it dies.
+                        keep = ~died
+                        uring = jnp.where(keep[None, :, None], uring, 0.0)
+                        if wired:
+                            cst = dict(cst,
+                                       acc=jnp.where(keep[:, None],
+                                                     cst["acc"], 0.0),
+                                       res=jnp.where(keep[:, None],
+                                                     cst["res"], 0.0),
+                                       xring=jnp.where(keep[None, :, None],
+                                                       cst["xring"], 0.0))
+                        if faulted:
+                            # a dying producer's unacked shipment and lane
+                            # copies vanish with it (oracle mirror)
+                            cst = wire.drop_pending(cst, keep)
+                    cview_pre = cview
+                else:
+                    rates = None
+
+                # global per-producer suffix-aggregate inf-norms: local block
+                # norms, max-reduced over the owning shards.
+                norms = jax.lax.pmax(
+                    ops.vap_suffix_norms(uring, uclock, c), "model")  # [W+1, P]
+
+                # --- 1. pre-read consistency enforcement (blocking fetches) --
+                if cfg.model == "bsp":
+                    forced = cview < (c - 1)
+                    cview = jnp.full_like(cview, c - 1)
+                elif cfg.model in ("ssp", "essp"):
+                    forced = cview < (c - s_eff - 1)
+                    if wired and faulted:
+                        # a faulted cross-pod refresh can only fetch what has
+                        # actually *arrived*: wire_tip caps the shipped
+                        # boundary (oracle mirror)
+                        tgt = jnp.where(in_pod, c - 1,
+                                        jnp.minimum(
+                                            comm.shipped_through(
+                                                c, cfg.agg_clocks),
+                                            cst["wire_tip"][None, :]))
+                        cview = jnp.where(forced, tgt, cview)
+                    elif wired:
+                        # cross-pod refreshes fetch what has *shipped* (through
+                        # the last aggregation boundary), mirroring the oracle
+                        tgt = jnp.where(in_pod, c - 1,
+                                        comm.shipped_through(c, cfg.agg_clocks))
+                        cview = jnp.where(forced, tgt, cview)
+                    else:
+                        cview = jnp.where(forced, c - 1, cview)
+                elif cfg.model == "vap":
+                    cview, forced = enforce_vap(cfg, c, cview, norms, W)
+                else:  # async
+                    forced = jnp.zeros_like(cview, dtype=bool)
+
+                if cfg.read_my_writes:
+                    cview = jnp.where(eye_l, c - 1, cview)
+
+                if churned:
+                    # dead readers neither fetch nor advance (oracle mirror)
+                    forced = forced & live_l[:, None]
+                    cview = jnp.where(live_l[:, None], cview, cview_pre)
+
+                staleness = cview - c                              # [Pl, P]
+
+                kcur = jnp.clip(c - 1 - cview, 0, W)               # [Pl, P]
+                intransit_inf = jax.lax.pmax(
+                    jnp.max(norms[kcur, producer_ids[None, :]]), worker_axes)
+
+            with jax.named_scope("psrun.view"):
+                # --- 2. materialize views: shard-local, then assemble --------
+                if wired:
+                    # intra-pod producers read raw, cross-pod producers read
+                    # the shipped wire ring; folded bases assemble per reader
+                    # pod — the same three-term sum as the oracle.
+                    cv_intra = jnp.where(in_pod, cview, RING_EMPTY)
+                    cv_xpod = jnp.where(in_pod, RING_EMPTY, cview)
+                    rb = comm.reader_base(base, cst["base_pod"],
+                                          cst["xbase_pod"], reader_pods)
+                    views_l = (rb
+                               + ops.ring_view(zeros_dl, uring, uclock,
+                                               cv_intra)
+                               + ops.ring_view(zeros_dl, cst["xring"], uclock,
+                                               cv_xpod))              # [Pl, dl]
+                else:
+                    views_l = ops.ring_view(base, uring, uclock, cview)
+                views = jax.lax.all_gather(views_l, "model", axis=1,
+                                           tiled=True)[:, :d]        # [Pl, d]
+
+            with jax.named_scope("psrun.update"):
+                # --- 3. worker computation (this shard's workers only) -------
+                upd_keys = jax.lax.dynamic_slice_in_dim(
+                    jax.random.split(k_upd, P), rows0, Pl)
+                u_l, local_new = vmapped_update(views, local, worker_ids, c,
+                                                upd_keys)
+                u_l = u_l.astype(f32)                              # [Pl, d]
+                if churned:
+                    # mask dead workers' pushes BEFORE the all-gather so the
+                    # gathered [P, d] (and u_l2 on it) matches the oracle's
+                    # masked operand bit for bit; freeze their local state.
+                    u_l = jnp.where(live_l[:, None], u_l, 0.0)
+                    local = jax.tree_util.tree_map(
+                        lambda new, old: jnp.where(
+                            live_l.reshape((Pl,) + (1,) * (new.ndim - 1)),
+                            new, old),
+                        local_new, local)
+                else:
+                    local = local_new
+
+            with jax.named_scope("psrun.push"):
+                # --- 4. push to owning shards; fold oldest slot --------------
+                # The all-gather over the worker axes is the data plane: under a
+                # pod axis it is the eager cross-pod delta channel (one fresh
+                # [P, d] update set per clock keeps every pod replica's ring
+                # reconciled; visibility stays gated by cview above).
+                u_all = jax.lax.all_gather(u_l, worker_axes, axis=0, tiled=True)
+                # norm on the gathered [P, d] — the oracle's operand shape, so
+                # XLA emits the same reduction and the floats match bit-for-bit
+                u_l2 = jnp.linalg.norm(u_all, axis=-1)
+                u_all = jnp.pad(u_all, ((0, 0), (0, dpad - d)))
+                u_blk = jax.lax.dynamic_slice(u_all, (0, mi * dl), (P, dl))
+                slot = jnp.mod(c, W)
+                old_valid = uclock[slot] > RING_INVALID
+                if wired:
+                    w_old = jnp.where(old_valid, 1.0, 0.0)
+                    cst = dict(cst,
+                               base_pod=cst["base_pod"]
+                               + w_old * comm.fold_pods(uring[slot], G),
+                               xbase_pod=cst["xbase_pod"]
+                               + w_old * comm.fold_pods(cst["xring"][slot], G))
+                else:
+                    base = base + jnp.where(old_valid, 1.0, 0.0) * jnp.sum(
+                        uring[slot], axis=0)
+                uring = uring.at[slot].set(u_blk)
+                uclock = uclock.at[slot].set(c)
+                if wired:
+                    # --- 4b. comm substrate: accumulate; ship on boundary ----
+                    # thresholds/scales/counts come from the *gathered* full
+                    # rows (bit-identical to the oracle's [P, d] sort); the
+                    # pack itself is elementwise on the local shard.
+                    acc = cst["acc"] + u_blk
+                    delta = acc + cst["res"]                     # [P, dl]
+                    delta_full = jax.lax.all_gather(
+                        delta, "model", axis=1, tiled=True)[:, :d]
+                    thresh = comm.row_threshold(delta_full, cfg.topk_frac)
+                    scale = comm.quant_scale(delta_full, cfg.quant)
+                    wire_u, resid = ops.delta_pack(delta, thresh, scale,
+                                                   cfg.quant)
+                    nnz = comm.selected_count(delta_full, thresh)
+                    ship = comm.ship_now(c, cfg.agg_clocks)
+                    if churned:
+                        # dead producers hold their shipment (drain policy:
+                        # acc/res keep the mass until the first boundary
+                        # after rejoin) — oracle mirror.
+                        ship = ship & live_now                 # [P]
                     if faulted:
-                        # a dying producer's unacked shipment and lane
-                        # copies vanish with it (oracle mirror)
-                        cst = wire.drop_pending(cst, keep)
-                cview_pre = cview
-            else:
-                rates = None
-
-            # global per-producer suffix-aggregate inf-norms: local block
-            # norms, max-reduced over the owning shards.
-            norms = jax.lax.pmax(
-                ops.vap_suffix_norms(uring, uclock, c), "model")  # [W+1, P]
-
-            # --- 1. pre-read consistency enforcement (blocking fetches) ---
-            if cfg.model == "bsp":
-                forced = cview < (c - 1)
-                cview = jnp.full_like(cview, c - 1)
-            elif cfg.model in ("ssp", "essp"):
-                forced = cview < (c - s_eff - 1)
-                if wired and faulted:
-                    # a faulted cross-pod refresh can only fetch what has
-                    # actually *arrived*: wire_tip caps the shipped
-                    # boundary (oracle mirror)
-                    tgt = jnp.where(in_pod, c - 1,
-                                    jnp.minimum(
-                                        comm.shipped_through(
-                                            c, cfg.agg_clocks),
-                                        cst["wire_tip"][None, :]))
-                    cview = jnp.where(forced, tgt, cview)
-                elif wired:
-                    # cross-pod refreshes fetch what has *shipped* (through
-                    # the last aggregation boundary), mirroring the oracle
-                    tgt = jnp.where(in_pod, c - 1,
-                                    comm.shipped_through(c, cfg.agg_clocks))
-                    cview = jnp.where(forced, tgt, cview)
+                        # stop-and-wait ARQ: a busy producer (previous
+                        # shipment unacked) skips the boundary — acc keeps
+                        # accumulating and the skipped content rides the
+                        # next shipment (oracle mirror).
+                        ship = ship & wire.idle(cst)           # [P]
+                    ship_b = ship[:, None] if (churned or faulted) else ship
+                    wire_u = jnp.where(ship_b, wire_u, jnp.zeros_like(wire_u))
+                    floats = comm.wire_floats(nnz, d, cfg.quant)
+                    if faulted:
+                        # shipments enter the wire ring only when they
+                        # *arrive*, via the seq-guarded fold in wire_step
+                        # (which also runs retransmits, give-up healing and
+                        # instant arrivals, and charges every transmission —
+                        # retries included — into ship_floats).
+                        cst = dict(cst,
+                                   acc=jnp.where(ship_b, jnp.zeros_like(acc),
+                                                 acc),
+                                   res=jnp.where(ship_b, resid, cst["res"]),
+                                   xring=cst["xring"].at[slot].set(
+                                       jnp.zeros_like(wire_u)))
+                        cst, ship_floats = wire.wire_step(
+                            cst, wire_u, floats, ship, c, flt,
+                            live=live_now if churned else None)
+                    else:
+                        cst = dict(cst,
+                                   acc=jnp.where(ship_b, jnp.zeros_like(acc),
+                                                 acc),
+                                   res=jnp.where(ship_b, resid, cst["res"]),
+                                   xring=cst["xring"].at[slot].set(wire_u))
+                        ship_floats = jnp.where(
+                            ship, floats, jnp.zeros((P,), f32))
                 else:
-                    cview = jnp.where(forced, c - 1, cview)
-            elif cfg.model == "vap":
-                cview, forced = enforce_vap(cfg, c, cview, norms, W)
-            else:  # async
-                forced = jnp.zeros_like(cview, dtype=bool)
+                    ship_floats = comm.dense_ship_floats(cfg.model, P, d)
+                    if churned:
+                        ship_floats = jnp.where(live_now, ship_floats, 0.0)
 
-            if cfg.read_my_writes:
-                cview = jnp.where(eye_l, c - 1, cview)
+                # --- 5. end-of-clock delivery (affects reads at c+1) ---------
+                if cfg.model == "bsp":
+                    delivered = jnp.ones((Pl, P), bool)
+                    if churned:
+                        delivered = delivered & live_l[:, None]
+                        cview = jnp.where(live_l[:, None],
+                                          jnp.full_like(cview, c), cview)
+                    else:
+                        cview = jnp.full_like(cview, c)
+                elif cfg.model == "ssp":
+                    delivered = jnp.zeros((Pl, P), bool)
+                else:  # essp / async / vap: delay-driven eager delivery
+                    delivered = jax.lax.dynamic_slice_in_dim(
+                        delivery_matrix(k_net, cfg, P, rates), rows0, Pl)
+                    if churned:
+                        delivered = delivered & live_l[:, None]
+                    if wired and faulted:
+                        # deliveries carry the latest *arrived* shipment:
+                        # boundary target capped by wire_tip (oracle mirror)
+                        tgt = jnp.where(in_pod, c,
+                                        jnp.minimum(
+                                            comm.shipped_end(
+                                                c, cfg.agg_clocks),
+                                            cst["wire_tip"][None, :]))
+                        cview = jnp.where(delivered, jnp.maximum(cview, tgt),
+                                          cview)
+                    elif wired:
+                        tgt = jnp.where(in_pod, c,
+                                        comm.shipped_end(c, cfg.agg_clocks))
+                        cview = jnp.where(delivered, jnp.maximum(cview, tgt),
+                                          cview)
+                    else:
+                        cview = jnp.where(delivered, c, cview)
 
-            if churned:
-                # dead readers neither fetch nor advance (oracle mirror)
-                forced = forced & live_l[:, None]
-                cview = jnp.where(live_l[:, None], cview, cview_pre)
-
-            staleness = cview - c                              # [Pl, P]
-
-            kcur = jnp.clip(c - 1 - cview, 0, W)               # [Pl, P]
-            intransit_inf = jax.lax.pmax(
-                jnp.max(norms[kcur, producer_ids[None, :]]), worker_axes)
-
-            # --- 2. materialize views: shard-local, then assemble ---------
-            if wired:
-                # intra-pod producers read raw, cross-pod producers read
-                # the shipped wire ring; folded bases assemble per reader
-                # pod — the same three-term sum as the oracle.
-                cv_intra = jnp.where(in_pod, cview, RING_EMPTY)
-                cv_xpod = jnp.where(in_pod, RING_EMPTY, cview)
-                rb = comm.reader_base(base, cst["base_pod"],
-                                      cst["xbase_pod"], reader_pods)
-                views_l = (rb
-                           + ops.ring_view(zeros_dl, uring, uclock,
-                                           cv_intra)
-                           + ops.ring_view(zeros_dl, cst["xring"], uclock,
-                                           cv_xpod))              # [Pl, dl]
-            else:
-                views_l = ops.ring_view(base, uring, uclock, cview)
-            views = jax.lax.all_gather(views_l, "model", axis=1,
-                                       tiled=True)[:, :d]        # [Pl, d]
-
-            # --- 3. worker computation (this shard's workers only) --------
-            upd_keys = jax.lax.dynamic_slice_in_dim(
-                jax.random.split(k_upd, P), rows0, Pl)
-            u_l, local_new = vmapped_update(views, local, worker_ids, c,
-                                            upd_keys)
-            u_l = u_l.astype(f32)                              # [Pl, d]
-            if churned:
-                # mask dead workers' pushes BEFORE the all-gather so the
-                # gathered [P, d] (and u_l2 on it) matches the oracle's
-                # masked operand bit for bit; freeze their local state.
-                u_l = jnp.where(live_l[:, None], u_l, 0.0)
-                local = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(
-                        live_l.reshape((Pl,) + (1,) * (new.ndim - 1)),
-                        new, old),
-                    local_new, local)
-            else:
-                local = local_new
-
-            # --- 4. push to owning shards; fold oldest slot ---------------
-            # The all-gather over the worker axes is the data plane: under a
-            # pod axis it is the eager cross-pod delta channel (one fresh
-            # [P, d] update set per clock keeps every pod replica's ring
-            # reconciled; visibility stays gated by cview above).
-            u_all = jax.lax.all_gather(u_l, worker_axes, axis=0, tiled=True)
-            # norm on the gathered [P, d] — the oracle's operand shape, so
-            # XLA emits the same reduction and the floats match bit-for-bit
-            u_l2 = jnp.linalg.norm(u_all, axis=-1)
-            u_all = jnp.pad(u_all, ((0, 0), (0, dpad - d)))
-            u_blk = jax.lax.dynamic_slice(u_all, (0, mi * dl), (P, dl))
-            slot = jnp.mod(c, W)
-            old_valid = uclock[slot] > RING_INVALID
-            if wired:
-                w_old = jnp.where(old_valid, 1.0, 0.0)
-                cst = dict(cst,
-                           base_pod=cst["base_pod"]
-                           + w_old * comm.fold_pods(uring[slot], G),
-                           xbase_pod=cst["xbase_pod"]
-                           + w_old * comm.fold_pods(cst["xring"][slot], G))
-            else:
-                base = base + jnp.where(old_valid, 1.0, 0.0) * jnp.sum(
-                    uring[slot], axis=0)
-            uring = uring.at[slot].set(u_blk)
-            uclock = uclock.at[slot].set(c)
-            if wired:
-                # --- 4b. comm substrate: accumulate; ship on boundary ----
-                # thresholds/scales/counts come from the *gathered* full
-                # rows (bit-identical to the oracle's [P, d] sort); the
-                # pack itself is elementwise on the local shard.
-                acc = cst["acc"] + u_blk
-                delta = acc + cst["res"]                     # [P, dl]
-                delta_full = jax.lax.all_gather(
-                    delta, "model", axis=1, tiled=True)[:, :d]
-                thresh = comm.row_threshold(delta_full, cfg.topk_frac)
-                scale = comm.quant_scale(delta_full, cfg.quant)
-                wire_u, resid = ops.delta_pack(delta, thresh, scale,
-                                               cfg.quant)
-                nnz = comm.selected_count(delta_full, thresh)
-                ship = comm.ship_now(c, cfg.agg_clocks)
-                if churned:
-                    # dead producers hold their shipment (drain policy:
-                    # acc/res keep the mass until the first boundary
-                    # after rejoin) — oracle mirror.
-                    ship = ship & live_now                 # [P]
-                if faulted:
-                    # stop-and-wait ARQ: a busy producer (previous
-                    # shipment unacked) skips the boundary — acc keeps
-                    # accumulating and the skipped content rides the
-                    # next shipment (oracle mirror).
-                    ship = ship & wire.idle(cst)           # [P]
-                ship_b = ship[:, None] if (churned or faulted) else ship
-                wire_u = jnp.where(ship_b, wire_u, jnp.zeros_like(wire_u))
-                floats = comm.wire_floats(nnz, d, cfg.quant)
-                if faulted:
-                    # shipments enter the wire ring only when they
-                    # *arrive*, via the seq-guarded fold in wire_step
-                    # (which also runs retransmits, give-up healing and
-                    # instant arrivals, and charges every transmission —
-                    # retries included — into ship_floats).
-                    cst = dict(cst,
-                               acc=jnp.where(ship_b, jnp.zeros_like(acc),
-                                             acc),
-                               res=jnp.where(ship_b, resid, cst["res"]),
-                               xring=cst["xring"].at[slot].set(
-                                   jnp.zeros_like(wire_u)))
-                    cst, ship_floats = wire.wire_step(
-                        cst, wire_u, floats, ship, c, flt,
-                        live=live_now if churned else None)
+            with jax.named_scope("psrun.record"):
+                # --- 6. record (gathered so losses match the oracle exactly) --
+                if wired:
+                    x_ref = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
+                        uring * (uclock[:, None, None] > RING_INVALID),
+                        axis=(0, 1))
                 else:
-                    cst = dict(cst,
-                               acc=jnp.where(ship_b, jnp.zeros_like(acc),
-                                             acc),
-                               res=jnp.where(ship_b, resid, cst["res"]),
-                               xring=cst["xring"].at[slot].set(wire_u))
-                    ship_floats = jnp.where(
-                        ship, floats, jnp.zeros((P,), f32))
-            else:
-                ship_floats = comm.dense_ship_floats(cfg.model, P, d)
-                if churned:
-                    ship_floats = jnp.where(live_now, ship_floats, 0.0)
-
-            # --- 5. end-of-clock delivery (affects reads at c+1) ----------
-            if cfg.model == "bsp":
-                delivered = jnp.ones((Pl, P), bool)
-                if churned:
-                    delivered = delivered & live_l[:, None]
-                    cview = jnp.where(live_l[:, None],
-                                      jnp.full_like(cview, c), cview)
-                else:
-                    cview = jnp.full_like(cview, c)
-            elif cfg.model == "ssp":
-                delivered = jnp.zeros((Pl, P), bool)
-            else:  # essp / async / vap: delay-driven eager delivery
-                delivered = jax.lax.dynamic_slice_in_dim(
-                    delivery_matrix(k_net, cfg, P, rates), rows0, Pl)
-                if churned:
-                    delivered = delivered & live_l[:, None]
-                if wired and faulted:
-                    # deliveries carry the latest *arrived* shipment:
-                    # boundary target capped by wire_tip (oracle mirror)
-                    tgt = jnp.where(in_pod, c,
-                                    jnp.minimum(
-                                        comm.shipped_end(
-                                            c, cfg.agg_clocks),
-                                        cst["wire_tip"][None, :]))
-                    cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                      cview)
-                elif wired:
-                    tgt = jnp.where(in_pod, c,
-                                    comm.shipped_end(c, cfg.agg_clocks))
-                    cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                      cview)
-                else:
-                    cview = jnp.where(delivered, c, cview)
-
-            # --- 6. record (gathered so losses match the oracle exactly) --
-            if wired:
-                x_ref = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
-                    uring * (uclock[:, None, None] > RING_INVALID),
-                    axis=(0, 1))
-            else:
-                x_ref = base + jnp.sum(
-                    uring * (uclock[:, None, None] > RING_INVALID),
-                    axis=(0, 1))
-            x_ref = jax.lax.all_gather(x_ref, "model", tiled=True)[:d]
-            locals_all = jax.tree_util.tree_map(
-                lambda x: jax.lax.all_gather(x, worker_axes, axis=0,
-                                             tiled=True),
-                local)
-            views_all = jax.lax.all_gather(  # analysis: ignore[unmasked-gather] -- record-side gather of reader *views* for trace metrics, not a producer reduction; dead readers' rows are inert (their cview froze) and the oracle gathers identically
-                views, worker_axes, axis=0, tiled=True)
-            out = dict(loss_ref=app.loss(x_ref, locals_all),
-                       loss_view=app.loss(views_all[0], locals_all),
-                       staleness=staleness, forced=forced,
-                       delivered=delivered,
-                       u_l2=u_l2, intransit_inf=intransit_inf,
-                       ship_floats=ship_floats,
-                       live=live_now if churned
-                       else jnp.ones((P,), bool))
-            if record_views:
-                out["views0"] = views_all[0]
-            if obs_enabled:
-                # shard-local fold of this clock's step values; shards
-                # merge once after the scan (device_reduce), not per clock
-                oacc = obsm.device_update(
-                    oacc, staleness=staleness, forced=forced,
-                    delivered=delivered, ship_floats=ship_floats,
-                    live=out["live"],
-                    live_rows=live_l if churned
-                    else jnp.ones((Pl,), bool),
-                    in_pod=in_pod_obs)
+                    x_ref = base + jnp.sum(
+                        uring * (uclock[:, None, None] > RING_INVALID),
+                        axis=(0, 1))
+                x_ref = jax.lax.all_gather(x_ref, "model", tiled=True)[:d]
+                locals_all = jax.tree_util.tree_map(
+                    lambda x: jax.lax.all_gather(x, worker_axes, axis=0,
+                                                 tiled=True),
+                    local)
+                views_all = jax.lax.all_gather(  # analysis: ignore[unmasked-gather] -- record-side gather of reader *views* for trace metrics, not a producer reduction; dead readers' rows are inert (their cview froze) and the oracle gathers identically
+                    views, worker_axes, axis=0, tiled=True)
+                out = dict(loss_ref=app.loss(x_ref, locals_all),
+                           loss_view=app.loss(views_all[0], locals_all),
+                           staleness=staleness, forced=forced,
+                           delivered=delivered,
+                           u_l2=u_l2, intransit_inf=intransit_inf,
+                           ship_floats=ship_floats,
+                           live=live_now if churned
+                           else jnp.ones((P,), bool))
+                if record_views:
+                    out["views0"] = views_all[0]
+                if obs_enabled:
+                    # shard-local fold of this clock's step values; shards
+                    # merge once after the scan (device_reduce), not per clock
+                    oacc = obsm.device_update(
+                        oacc, staleness=staleness, forced=forced,
+                        delivered=delivered, ship_floats=ship_floats,
+                        live=out["live"],
+                        live_rows=live_l if churned
+                        else jnp.ones((Pl,), bool),
+                        in_pod=in_pod_obs)
             new_carry = ((base, uring, uclock, cview, local, rng, cst)
                          if wired else
                          (base, uring, uclock, cview, local, rng))
@@ -699,22 +718,35 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 f"the knobs are static — build a new run fn")
         return f
 
+    def _args(state, cfg_run, schedule, faults):
+        return (state, _norm_cfg(cfg_run), _norm_sched(schedule),
+                _norm_faults(faults))
+
     def run_from(state: PSState, cfg_run: ConsistencyConfig | None = None,
                  schedule: ChurnSchedule | None = None,
                  faults: wire.WireFaults | None = None):
         """Advance ``state`` by ``n_clocks``; returns ``(Trace, PSState)``.
         Bit-identical to running the clocks uninterrupted."""
-        return jitted(state, _norm_cfg(cfg_run), _norm_sched(schedule),
-                      _norm_faults(faults))
+        with jax.profiler.TraceAnnotation("psrun.dispatch"):
+            return jitted(*_args(state, cfg_run, schedule, faults))
 
     def fn(seed, cfg_run: ConsistencyConfig | None = None,
            schedule: ChurnSchedule | None = None,
            faults: wire.WireFaults | None = None) -> Trace:
-        return jitted(init_state(seed), _norm_cfg(cfg_run),
-                      _norm_sched(schedule), _norm_faults(faults))[0]
+        with jax.profiler.TraceAnnotation("psrun.dispatch"):
+            return jitted(*_args(init_state(seed), cfg_run, schedule,
+                                 faults))[0]
+
+    def lower(state: PSState, cfg_run: ConsistencyConfig | None = None,
+              schedule: ChurnSchedule | None = None,
+              faults: wire.WireFaults | None = None):
+        """``run_from``'s program for ``state``, lowered (``jax.stages``):
+        ``.compile().as_text()`` names the stages and kernels it holds."""
+        return jitted.lower(*_args(state, cfg_run, schedule, faults))
 
     fn.init_state = init_state
     fn.run_from = run_from
+    fn.lower = lower
     return fn
 
 

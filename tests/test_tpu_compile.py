@@ -6,7 +6,7 @@ block shapes off the (8, 128) tiling, too much fast memory, an
 unpartitionable kernel.  These tests compile each kernel at real widths —
 the PS kernels at the padded width of MF at rank 100 over 16,384 users and
 17,770 items, flash attention at Qwen3-0.6B's widths — and assert that the
-program holds the kernel (``tpu_custom_call``).  The kernels are called
+program holds the kernel (``tpu_custom_call``) under its name.  The kernels are called
 with ``interpret=False`` directly, because ``ops.get_backend()`` sees the
 CPU here.
 
@@ -17,6 +17,7 @@ collect alike in every test worker.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,15 +57,22 @@ def _shape(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(name, fn, *args):
+    """The compiled program holds the kernel, under its ``name=`` (inside
+    the transformations applied to it: ``jvp(flash_attention)``)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    kernel = re.compile(rf"(\w+\()*{name}\)*")
+    assert any(kernel.fullmatch(part)
+               for op_name in re.findall(r'op_name="([^"]*)"', text)
+               for part in op_name.split("/"))
 
 
 @pytest.mark.parametrize("readers", [P_WORKERS, P_WORKERS // 2])
 def test_ring_view_compiles(one_chip, readers):
     """All readers (one worker shard) and half of them (two data shards)."""
     _assert_kernel(
+        "ring_view",
         lambda b, u, uc, cv: ps_view.ring_view(b, u, uc, cv, interpret=False),
         _shape(one_chip, (D_MF,)),
         _shape(one_chip, (W_RING, P_WORKERS, D_MF)),
@@ -74,6 +82,7 @@ def test_ring_view_compiles(one_chip, readers):
 
 def test_vap_suffix_norms_compiles(one_chip):
     _assert_kernel(
+        "vap_suffix_norms",
         lambda u, uc, c: ps_view.vap_suffix_norms(u, uc, c, interpret=False),
         _shape(one_chip, (W_RING, P_WORKERS, D_MF)),
         _shape(one_chip, (W_RING,), jnp.int32),
@@ -83,6 +92,7 @@ def test_vap_suffix_norms_compiles(one_chip):
 @pytest.mark.parametrize("quant", ["f32", "int8"])
 def test_delta_pack_compiles(one_chip, quant):
     _assert_kernel(
+        "delta_pack",
         lambda d, t, s: dp.delta_pack(d, t, s, quant, interpret=False),
         _shape(one_chip, (P_WORKERS, D_MF)),
         _shape(one_chip, (P_WORKERS,)),
@@ -100,6 +110,7 @@ def _attention_args(one_chip):
 def test_flash_attention_forward_compiles(one_chip):
     scale = QWEN3["Dh"] ** -0.5
     _assert_kernel(
+        "flash_attention",
         lambda q, k, v, pos: fa.flash_attention(
             q, k, v, scale=scale, q_pos=pos, kv_pos=pos, interpret=False),
         *_attention_args(one_chip))
@@ -118,7 +129,8 @@ def test_flash_attention_grad_compiles(one_chip):
 
     ops.set_backend("pallas")
     try:
-        _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _assert_kernel("flash_attention",
+                       jax.value_and_grad(loss, argnums=(0, 1, 2)),
                        *_attention_args(one_chip))
     finally:
         ops.set_backend("auto")
