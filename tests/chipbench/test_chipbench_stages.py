@@ -166,6 +166,40 @@ def test_unnamed_program_is_all_other(old_trace):
     assert got["stage_ms.other"] == pytest.approx(1e3 * red["busy_s"] / 30)
 
 
+# ---- a trace recorded with the stages named: mf-netflix.essp3, 3 x 10 ----
+NAMED = DATA / "essp3.stages.xplane.pb.gz"
+
+
+@pytest.fixture(scope="module")
+def named_trace():
+    events = reduce.load_xspace(NAMED)
+    return events, reduce.reduce_events(events, SPANS)
+
+
+def test_named_program_stage_times(named_trace):
+    _, red = named_trace
+    got = stages.stage_ms(red, stages.load_op_scopes(NAMED), clocks=30)
+    assert got == {"stage_ms.enforce": pytest.approx(12.44043107),
+                   "stage_ms.view": pytest.approx(15.23993117),
+                   "stage_ms.update": pytest.approx(3.4779071),
+                   "stage_ms.push": pytest.approx(1.21024773),
+                   "stage_ms.record": pytest.approx(57.6319676),
+                   "stage_ms.other": pytest.approx(13.5968745)}
+    total = sum(got.values())
+    assert total == pytest.approx(1e3 * red["busy_s"] / 30, rel=5e-3)
+    assert total - got["stage_ms.other"] >= 0.8 * total
+
+
+def test_named_program_gaps_and_dispatch(named_trace):
+    events, red = named_trace
+    # the runtime's spans change no label on this trace: each gap inside
+    # a dispatch begins before the runtime's own span does
+    assert stages.idle_gaps(events, SPANS) == red["idle_gaps"]
+    assert stages.dispatch_s(events, SPANS) == [
+        pytest.approx(0.003445209), pytest.approx(0.002921219),
+        pytest.approx(0.00312621)]
+
+
 def test_traced_segments_record_the_runtime_dispatch(tmp_path):
     """The hook profiles three segments of a small job on the CPU; each
     segment's dispatch holds the runtime's own ``psrun.dispatch`` span."""
