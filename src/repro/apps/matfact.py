@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ..core.ps import PSApp
 from ..core.timemodel import TimeModel
+from ..kernels import mf_sse, ops
 
 
 def mf_time_model(**kw) -> TimeModel:
@@ -58,6 +59,10 @@ class MFConfig:
 
 def _pack(L, R):
     return jnp.concatenate([L.ravel(), R.ravel()])
+
+
+def _lanes(m: int) -> int:
+    return -(-m // 128) * 128
 
 
 def make_mf_app(cfg: MFConfig) -> PSApp:
@@ -113,11 +118,29 @@ def make_mf_app(cfg: MFConfig) -> PSApp:
         # ratings come in as arguments (not closed-over constants), so a
         # large rating set is not baked into the compiled program
         L, R = unpack(x)
+        if "dc" in locals_:
+            # dense form: the counted ratings on the [n, m_pad] grid
+            rows = (-1, locals_["dc"].shape[-1])
+            sse = ops.mf_sse(L, R, locals_["dv"].reshape(rows),
+                             locals_["dc"].reshape(rows))
+            return sse / locals_["vv"].size
         all_i, all_j = locals_["ii"].ravel(), locals_["jj"].ravel()
         pred = jnp.sum(L[all_i] * R[:, all_j].T, axis=-1)
         return jnp.mean(jnp.square(locals_["vv"].ravel() - pred))
 
     local0 = {"ii": ii, "jj": jj, "vv": vv}
+    if ops.get_backend() != "ref" and mf_sse.supported(n, _lanes(m)):
+        # Each worker's ratings on its rows of the grid, for the loss only
+        # (worker_update samples ii/jj/vv): dc counts each observed pair
+        # (duplicates keep their weight), dv is D there and 0 elsewhere, so
+        # unobserved truth never enters a worker's data.  The grid holds
+        # n·m_pad·5 bytes whatever the density.
+        m_pad = _lanes(m)
+        w = jnp.broadcast_to(jnp.arange(P)[:, None], ii.shape)
+        dc = jnp.zeros((P, rows_per, m_pad), jnp.int8).at[
+            w, ii - w * rows_per, jj].add(jnp.int8(1))
+        D_w = jnp.pad(D, ((0, 0), (0, m_pad - m))).reshape(P, rows_per, m_pad)
+        local0.update(dv=jnp.where(dc > 0, D_w, 0.0), dc=dc)
     return PSApp(name="matfact", dim=(n + m) * k, n_workers=P,
                  x0=_pack(L0, R0), local0=local0,
                  worker_update=worker_update, loss=loss)

@@ -10,8 +10,8 @@ On a Pallas backend nothing falls back in silence: the PS kernels pad the
 lane axis ``d`` up to the kernel block (zeros change no sum, inf-norm or
 pack) and slice the result, and a shape no kernel supports raises.
 The kernels' ``pallas_call``s carry ``name=`` (``ring_view``,
-``vap_suffix_norms``, ``delta_pack``, ``flash_attention``): a compiled
-program that took one holds its name in its ops' ``op_name``.
+``vap_suffix_norms``, ``delta_pack``, ``mf_sse``, ``flash_attention``): a
+compiled program that took one holds its name in its ops' ``op_name``.
 """
 from __future__ import annotations
 
@@ -155,6 +155,22 @@ def delta_pack(delta, thresh, scale, quant: str = "f32"):
     wire, res = dp.delta_pack(delta_p, thresh, scale, quant,
                               interpret=interpret)
     return wire[:, :d], res[:, :d]
+
+
+def mf_sse(L, R, V, C):
+    """MF's dense-block squared error; see `ref.mf_sse` for the contract.
+
+    On a Pallas backend ``R [k, m]`` is padded with zero columns to ``V``'s
+    width (a multiple of 128 lanes)."""
+    interpret = _pallas()
+    if interpret is None:
+        return ref.mf_sse(L, R, V, C)
+    from . import mf_sse as sse
+    n, m_pad = V.shape
+    if not sse.supported(n, m_pad):
+        _unsupported("mf_sse", V.shape, "needs n % 8 == 0, m_pad % 128 == 0")
+    R = jnp.pad(R, ((0, 0), (0, m_pad - R.shape[1])))
+    return sse.mf_sse(L, R, V, C, interpret=interpret)
 
 
 def mf_sgd_block(L, R, D, mask, gamma, lam):

@@ -245,6 +245,19 @@ def mf_sgd_block(L, R, D, mask, gamma, lam):
     return dL, dR, loss
 
 
+def mf_sse(L, R, V, C):
+    """MF's squared error over a dense grid of counted ratings.
+
+    L [n,k], R [k,m] (the flat table's own layout), V [n,m_pad] ratings and
+    C [n,m_pad] int8 counts with m_pad >= m; columns past m have C = 0.
+    Returns Σ C·(V − L R)² in f32: a pair rated twice counts twice, and V
+    where C = 0 counts not at all.
+    """
+    R = jnp.pad(R, ((0, 0), (0, V.shape[1] - R.shape[1])))
+    pred = jnp.dot(L, R, precision=jax.lax.Precision.HIGHEST)
+    return jnp.sum(C.astype(jnp.float32) * jnp.square(V - pred))
+
+
 # ==========================================================================
 # Mamba-2 SSD (state-space duality) chunked scan
 # ==========================================================================

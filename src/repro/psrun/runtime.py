@@ -69,11 +69,12 @@ bit for bit (``checkpoint.io.save_runtime`` round-trips it through disk —
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P_
 
 from ..comm import substrate as comm
@@ -158,6 +159,25 @@ def _layout(app: PSApp, mesh, worker_axes):
     return DP, M, P // DP, dpad, dpad // M
 
 
+def _local_fixed(app: PSApp) -> bool:
+    """Whether ``app.worker_update`` hands its worker-local state back
+    unchanged (MF's ratings, read only by the loss).  The runtime then
+    keeps that state out of the scan's carry and out of the program's
+    outputs, gathers it across worker shards once a segment, not once a
+    clock, and returns the arrays it was given, placed once with the
+    program's input sharding: a segment neither carries, moves nor writes
+    out a copy of it."""
+    one = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                       app.local0)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    jaxpr = jax.make_jaxpr(app.worker_update)(
+        jax.ShapeDtypeStruct((app.dim,), jnp.float32), one, scalar, scalar,
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).jaxpr
+    n = len(jax.tree.leaves(one))
+    return all(o is i for o, i in zip(jaxpr.outvars[1:],
+                                      jaxpr.invars[1:1 + n], strict=True))
+
+
 def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 mesh=None, record_views: bool = False,
                 worker_axes: tuple = ("data",),
@@ -225,6 +245,7 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
     faulted = faults is not None
     if faulted:
         wire.validate_faults(faults, cfg, P, W)
+    local_fixed = _local_fixed(app)
 
     def body(cfg, clock0, base, uring, uclock, cview, local, rng,
              *extra):
@@ -275,6 +296,16 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         # (enforce, view, update, push, record).  The names reach only the
         # HLO ``op_name`` metadata, which a profiler trace keeps per device
         # op (``chipbench/stages.py`` reads it); the arithmetic is unchanged.
+        def gather_workers(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.lax.all_gather(x, worker_axes, axis=0,
+                                             tiled=True), tree)
+
+        local_in = local
+        if local_fixed:
+            with jax.named_scope("psrun.record"):
+                locals_fixed_all = gather_workers(local)
+
         def step(carry, c):
             if obs_enabled:
                 *carry, oacc = carry
@@ -282,6 +313,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 base, uring, uclock, cview, local, rng, cst = carry
             else:
                 base, uring, uclock, cview, local, rng = carry
+            if local_fixed:
+                local = local_in
             with jax.named_scope("psrun.enforce"):
                 rng, k_upd, k_net = jax.random.split(rng, 3)
 
@@ -528,10 +561,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                         uring * (uclock[:, None, None] > RING_INVALID),
                         axis=(0, 1))
                 x_ref = jax.lax.all_gather(x_ref, "model", tiled=True)[:d]
-                locals_all = jax.tree_util.tree_map(
-                    lambda x: jax.lax.all_gather(x, worker_axes, axis=0,
-                                                 tiled=True),
-                    local)
+                locals_all = (locals_fixed_all if local_fixed
+                              else gather_workers(local))
                 views_all = jax.lax.all_gather(  # analysis: ignore[unmasked-gather] -- record-side gather of reader *views* for trace metrics, not a producer reduction; dead readers' rows are inert (their cview froze) and the oracle gathers identically
                     views, worker_axes, axis=0, tiled=True)
                 out = dict(loss_ref=app.loss(x_ref, locals_all),
@@ -554,6 +585,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                         live_rows=live_l if churned
                         else jnp.ones((Pl,), bool),
                         in_pod=in_pod_obs)
+            if local_fixed:
+                local = None
             new_carry = ((base, uring, uclock, cview, local, rng, cst)
                          if wired else
                          (base, uring, uclock, cview, local, rng))
@@ -562,6 +595,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
             return new_carry, out
 
         clocks = clock0 + jnp.arange(n_clocks, dtype=jnp.int32)
+        if local_fixed:
+            local = None
         carry0 = ((base, uring, uclock, cview, local, rng, cst)
                   if wired else
                   (base, uring, uclock, cview, local, rng))
@@ -611,7 +646,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 for k in wire.WIRE_KEYS})
     state_specs = dict(clock=P_(), base=P_("model"),
                        uring=P_(None, None, "model"), uclock=P_(),
-                       cview=P_(worker_axes, None), local=local_spec,
+                       cview=P_(worker_axes, None),
+                       local=None if local_fixed else local_spec,
                        rng=P_(), comm=comm_specs)
     in_specs = [P_(), P_(), P_("model"), P_(None, None, "model"), P_(),
                 P_(worker_axes, None), local_spec, P_()]
@@ -659,7 +695,20 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                       obs=out.get("obs"))
         return trace, PSState(**out["state"])
 
-    jitted = jax.jit(run)
+    jit_run = jax.jit(run)
+    local_shardings = jax.tree_util.tree_map(
+        lambda _: NamedSharding(mesh, P_(worker_axes)), app.local0)
+
+    def place_local(local):
+        return jax.device_put(local, local_shardings) if local_fixed else local
+
+    def jitted(state: PSState, *rest):
+        if not local_fixed:
+            return jit_run(state, *rest)
+        state = replace(state, local=place_local(state.local))
+        trace, new = jit_run(state, *rest)
+        return (replace(trace, locals_final=state.local),
+                replace(new, local=state.local))
 
     def init_state(seed) -> PSState:
         """Clock-0 state for ``seed`` (the simulator's initial conditions,
@@ -670,7 +719,7 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
             uring=jnp.zeros((W, P, dpad), f32),
             uclock=jnp.full((W,), RING_EMPTY, jnp.int32),
             cview=jnp.full((P, P), -1, jnp.int32),
-            local=app.local0,
+            local=place_local(app.local0),
             rng=jax.random.PRNGKey(seed),
             comm=({**comm.init_state(W, P, dpad, G),
                    **wire.init_wire_state(P, dpad)} if faulted
@@ -742,7 +791,7 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
               faults: wire.WireFaults | None = None):
         """``run_from``'s program for ``state``, lowered (``jax.stages``):
         ``.compile().as_text()`` names the stages and kernels it holds."""
-        return jitted.lower(*_args(state, cfg_run, schedule, faults))
+        return jit_run.lower(*_args(state, cfg_run, schedule, faults))
 
     fn.init_state = init_state
     fn.run_from = run_from

@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mf_sgd import mf_sgd_block
+from repro.kernels.mf_sse import mf_sse
 from repro.kernels.ssd_scan import ssd
 from repro.kernels import ops
 
@@ -163,6 +164,53 @@ def test_mf_sgd_hypothesis(nb, mb, k, density, seed):
     dLg, dRg, lg = mf_sgd_block(L, R, D, mask, 0.05, 1e-4, interpret=True)
     np.testing.assert_allclose(np.asarray(dLg), np.asarray(dLw), atol=1e-3)
     np.testing.assert_allclose(np.asarray(dRg), np.asarray(dRw), atol=1e-3)
+
+
+MF_SSE_CASES = {
+    # n, m, k, block_n, block_m: m is never a multiple of 128
+    "ragged-k24": (64, 300, 24, 24, 128),            # ragged row blocks
+    "k100": (48, 200, 100, 512, 2048),
+    "empty-column-block": (40, 520, 24, 16, 128),
+    "value-without-count": (32, 300, 24, 32, 256),   # ragged column blocks
+}
+
+
+@pytest.mark.parametrize("case", sorted(MF_SSE_CASES))
+def test_mf_sse_matches_gather_form(case):
+    """The dense-block objective equals the per-rating gather form over the
+    same ratings: pairs rated 2 and 3 times count 2 and 3 times, a column
+    block with no rating adds nothing, and a value where the count is 0
+    does not count."""
+    n, m, k, block_n, block_m = MF_SSE_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    L = 0.3 * jax.random.normal(ks[0], (n, k))
+    R = 0.3 * jax.random.normal(ks[1], (k, m))
+    D = jax.random.normal(ks[2], (n, m))
+    N = n * m // 6
+    ii = jax.random.randint(ks[3], (N,), 0, n)
+    jj = jax.random.randint(ks[4], (N,), 0, m)
+    if case == "empty-column-block":
+        jj = jnp.where((jj >= 128) & (jj < 256), jj - 128, jj)
+    # duplicated pairs: the first 5 ratings twice more, the next 5 once
+    ii = jnp.concatenate([ii, ii[:5], ii[:10]])
+    jj = jnp.concatenate([jj, jj[:5], jj[:10]])
+    vv = D[ii, jj]
+    m_pad = -(-m // 128) * 128
+    C = jnp.zeros((n, m_pad), jnp.int8).at[ii, jj].add(jnp.int8(1))
+    assert {2, 3} <= set(np.unique(np.asarray(C)).tolist())
+    V = jnp.where(C > 0, jnp.pad(D, ((0, 0), (0, m_pad - m))), 0.0)
+    if case == "empty-column-block":
+        assert not np.asarray(C[:, 128:256]).any()
+    if case == "value-without-count":
+        V = jnp.where(C > 0, V, 5.0 + jax.random.normal(ks[5], V.shape))
+    want = float(jnp.sum(jnp.square(
+        vv - jnp.sum(L[ii] * R[:, jj].T, axis=-1))))
+    R_pad = jnp.pad(R, ((0, 0), (0, m_pad - m)))
+    got = float(mf_sse(L, R_pad, V, C, block_n=block_n, block_m=block_m,
+                       interpret=True))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    np.testing.assert_allclose(float(ref.mf_sse(L, R, V, C)), want,
+                               rtol=1e-6)
 
 
 def test_ops_backend_dispatch():

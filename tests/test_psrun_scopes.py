@@ -81,3 +81,18 @@ def test_kernels_run_in_their_stages(app):
                 where.setdefault(kernel, set()).add(_stage(n))
     assert where == {"vap_suffix_norms": {"enforce"}, "ring_view": {"view"},
                      "delta_pack": {"push"}}
+
+
+def test_mf_objective_kernel_runs_in_record():
+    """An MF app built on a Pallas backend evaluates its objective with the
+    ``mf_sse`` kernel, twice a clock (the table and worker 0's view), in
+    ``psrun.record`` and in no other stage."""
+    ops.set_backend("pallas_interpret")
+    try:
+        dense = make_mf_app(MFConfig(n_rows=16, n_cols=12, rank=4,
+                                     n_workers=4, batch=8))
+        names = _op_names(dense, CONFIGS["essp"])
+    finally:
+        ops.set_backend("auto")
+    stages = {_stage(n) for n in names if "mf_sse" in n.split("/")}
+    assert stages == {"record"}

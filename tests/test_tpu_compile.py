@@ -5,7 +5,8 @@ Interpret mode cannot see what Mosaic (TPU's Pallas compiler) refuses:
 block shapes off the (8, 128) tiling, too much fast memory, an
 unpartitionable kernel.  These tests compile each kernel at real widths —
 the PS kernels at the padded width of MF at rank 100 over 16,384 users and
-17,770 items, flash attention at Qwen3-0.6B's widths — and assert that the
+17,770 items, MF's dense objective over 32,768 users, flash attention at
+Qwen3-0.6B's widths — and assert that the
 program holds the kernel (``tpu_custom_call``) under its name.  The kernels are called
 with ``interpret=False`` directly, because ``ops.get_backend()`` sees the
 CPU here.
@@ -26,11 +27,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import delta_pack as dp
 from repro.kernels import flash_attention as fa
-from repro.kernels import ops, ps_view
+from repro.kernels import mf_sse, ops, ps_view
 
 # MF at rank 100 x (16,384 users + 17,770 items), padded to 128 lanes.
 D_MF = 3_415_424
 W_RING, P_WORKERS = 5, 8            # essp(3) ring window, 8 workers
+# MF's dense objective: 32,768 users x 17,770 items (padded to 128 lanes)
+N_MF, M_PAD_MF, K_MF = 32_768, 17_792, 100
 QWEN3 = dict(B=4, S=512, H=16, Hkv=8, Dh=128)
 
 
@@ -97,6 +100,18 @@ def test_delta_pack_compiles(one_chip, quant):
         _shape(one_chip, (P_WORKERS, D_MF)),
         _shape(one_chip, (P_WORKERS,)),
         _shape(one_chip, (P_WORKERS,)))
+
+
+def test_mf_sse_compiles(one_chip):
+    """MF's dense-block objective at the Netflix cell's widths: ragged
+    column blocks, k = 100 unpadded, the int8 count block."""
+    _assert_kernel(
+        "mf_sse",
+        lambda L, R, V, C: mf_sse.mf_sse(L, R, V, C, interpret=False),
+        _shape(one_chip, (N_MF, K_MF)),
+        _shape(one_chip, (K_MF, M_PAD_MF)),
+        _shape(one_chip, (N_MF, M_PAD_MF)),
+        _shape(one_chip, (N_MF, M_PAD_MF), jnp.int8))
 
 
 def _attention_args(one_chip):
