@@ -22,7 +22,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..core.ps import PSApp
+from ..core.ps import PSApp, fold_workers
 from ..core.timemodel import TimeModel
 
 
@@ -126,8 +126,9 @@ def make_lda_app(cfg: LDAConfig) -> PSApp:
         new_local = dict(local, z=z, ndk=ndk)
         return delta.ravel(), new_local
 
-    def loss(x, locals_):
-        """Negative predictive log-likelihood per token (lower = better)."""
+    def loss_sum(x, locals_, _worker_ids):
+        """Negative predictive log-likelihood summed over each worker's
+        tokens, and their count per worker."""
         nkw = jnp.maximum(x.reshape(K, V), 0.0)
         phi = (nkw + cfg.beta) / (jnp.sum(nkw, -1, keepdims=True) + V * cfg.beta)
         ndk = locals_["ndk"]                                  # [P, docs_per, K]
@@ -140,8 +141,14 @@ def make_lda_app(cfg: LDAConfig) -> PSApp:
             theta, d[:, :, None], axis=1)                     # [P, ntok, K]
         ph = phi[:, w].transpose(1, 2, 0)                     # [P, ntok, K]
         ll = jnp.log(jnp.sum(th * ph, axis=-1) + 1e-30)
-        return -jnp.mean(ll)
+        return -jnp.sum(ll, axis=-1), ll.shape[1]
+
+    def loss(x, locals_):
+        """Negative predictive log-likelihood per token (lower = better)."""
+        nll, count = loss_sum(x, locals_, None)
+        return fold_workers(nll) / (count * P)
 
     local0 = {"words": words, "docid": docid, "z": z0, "ndk": ndk0}
     return PSApp(name="lda", dim=K * V, n_workers=P, x0=nkw0.ravel(),
-                 local0=local0, worker_update=worker_update, loss=loss)
+                 local0=local0, worker_update=worker_update, loss=loss,
+                 loss_sum=loss_sum)

@@ -19,7 +19,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..core.ps import PSApp
+from ..core.ps import PSApp, fold_workers
 from ..core.timemodel import TimeModel
 from ..kernels import mf_sse, ops
 
@@ -66,6 +66,9 @@ def _lanes(m: int) -> int:
 
 
 def make_mf_app(cfg: MFConfig) -> PSApp:
+    """MF's app on synthetic data drawn from ``cfg.seed``: a rank
+    ``true_rank`` truth plus noise, each worker's ratings drawn from its
+    own block of rows (`mf_app`)."""
     n, m, k, P = cfg.n_rows, cfg.n_cols, cfg.rank, cfg.n_workers
     rng = jax.random.PRNGKey(cfg.seed)
     k_t, k_o, k_n, k_i = jax.random.split(rng, 4)
@@ -97,6 +100,28 @@ def make_mf_app(cfg: MFConfig) -> PSApp:
     kLi, kRi = jax.random.split(k_i)
     L0 = cfg.init_scale * jax.random.normal(kLi, (n, k))
     R0 = cfg.init_scale * jax.random.normal(kRi, (k, m))
+    return mf_app(cfg, _pack(L0, R0), ii, jj, vv)
+
+
+def mf_app(cfg: MFConfig, x0, ii, jj, vv) -> PSApp:
+    """MF's app on given data: the factors ``x0 [(n + m) k]`` and each
+    worker's ratings ``ii``, ``jj``, ``vv [P, n_obs]`` (worker ``p``'s
+    rows lie in its block ``[p n/P, (p+1) n/P)``; a (row, column) pair
+    drawn twice holds one rating, since the dense grid below keeps one
+    value per pair).  ``cfg`` gives the shapes and the update's knobs; its
+    data knobs are not read.
+
+    On a Pallas backend, where the kernel takes the shape, ``local0``
+    gains each worker's ratings on its rows of the dense grid, built from
+    that worker's ratings alone, one worker after another.  A caller may
+    hand in the ratings of a block of workers only (``ii`` etc. ``[Pl,
+    n_obs]``), as a ``shard_map`` over the worker axes does: each shard
+    then builds its own workers' grids where their ratings are."""
+    n, m, k, P = cfg.n_rows, cfg.n_cols, cfg.rank, cfg.n_workers
+    assert n % P == 0, "n_rows must divide by n_workers"
+    rows_per = n // P
+    n_obs_per = ii.shape[1]
+    m_pad = _lanes(m)
 
     def unpack(x):
         return x[: n * k].reshape(n, k), x[n * k:].reshape(k, m)
@@ -114,36 +139,47 @@ def make_mf_app(cfg: MFConfig) -> PSApp:
             (gamma * (e[:, None] * Li - cfg.lam * Rj)).T)
         return _pack(dL, dR), local
 
-    def loss(x, locals_):
+    def loss_sum(x, locals_, worker_ids):
         # ratings come in as arguments (not closed-over constants), so a
         # large rating set is not baked into the compiled program
         L, R = unpack(x)
+        ii_b, jj_b, vv_b = locals_["ii"], locals_["jj"], locals_["vv"]
         if "dc" in locals_:
-            # dense form: the counted ratings on the [n, m_pad] grid
-            rows = (-1, locals_["dc"].shape[-1])
-            sse = ops.mf_sse(L, R, locals_["dv"].reshape(rows),
-                             locals_["dc"].reshape(rows))
-            return sse / locals_["vv"].size
-        all_i, all_j = locals_["ii"].ravel(), locals_["jj"].ravel()
-        pred = jnp.sum(L[all_i] * R[:, all_j].T, axis=-1)
-        return jnp.mean(jnp.square(locals_["vv"].ravel() - pred))
+            # dense form: each worker's counted ratings on its rows of the
+            # grid, against its rows of L (one kernel, a grid step per
+            # worker block)
+            Lw = jax.lax.dynamic_slice_in_dim(
+                L.reshape(P, rows_per, k), worker_ids[0], ii_b.shape[0])
+            sse = jax.vmap(lambda Lp, V, C: ops.mf_sse(Lp, R, V, C))(
+                Lw, locals_["dv"], locals_["dc"])
+            return sse, n_obs_per
+        pred = jnp.sum(L[ii_b.ravel()] * R[:, jj_b.ravel()].T, axis=-1)
+        err = vv_b - pred.reshape(vv_b.shape)
+        return jnp.sum(jnp.square(err), axis=-1), n_obs_per
+
+    def loss(x, locals_):
+        sse, count = loss_sum(x, locals_, jnp.arange(P, dtype=jnp.int32))
+        return fold_workers(sse) / (count * P)
 
     local0 = {"ii": ii, "jj": jj, "vv": vv}
-    if ops.get_backend() != "ref" and mf_sse.supported(n, _lanes(m)):
+    if ops.get_backend() != "ref" and mf_sse.supported(rows_per, m_pad):
         # Each worker's ratings on its rows of the grid, for the loss only
         # (worker_update samples ii/jj/vv): dc counts each observed pair
-        # (duplicates keep their weight), dv is D there and 0 elsewhere, so
-        # unobserved truth never enters a worker's data.  The grid holds
-        # n·m_pad·5 bytes whatever the density.
-        m_pad = _lanes(m)
-        w = jnp.broadcast_to(jnp.arange(P)[:, None], ii.shape)
-        dc = jnp.zeros((P, rows_per, m_pad), jnp.int8).at[
-            w, ii - w * rows_per, jj].add(jnp.int8(1))
-        D_w = jnp.pad(D, ((0, 0), (0, m_pad - m))).reshape(P, rows_per, m_pad)
-        local0.update(dv=jnp.where(dc > 0, D_w, 0.0), dc=dc)
+        # (duplicates keep their weight), dv holds the rating there and 0
+        # elsewhere, so unobserved truth never enters a worker's data.
+        # The grid holds n·m_pad·5 bytes whatever the density.
+        def grid(ratings):
+            i, j, v = ratings
+            r = i % rows_per               # the row within the worker's block
+            dc = jnp.zeros((rows_per, m_pad), jnp.int8).at[r, j].add(
+                jnp.int8(1))
+            return jnp.zeros((rows_per, m_pad), vv.dtype).at[r, j].set(v), dc
+
+        dv, dc = jax.lax.map(grid, (ii, jj, vv))
+        local0.update(dv=dv, dc=dc)
     return PSApp(name="matfact", dim=(n + m) * k, n_workers=P,
-                 x0=_pack(L0, R0), local0=local0,
-                 worker_update=worker_update, loss=loss)
+                 x0=x0, local0=local0, worker_update=worker_update,
+                 loss=loss, loss_sum=loss_sum)
 
 
 def sequential_baseline(cfg: MFConfig, n_clocks: int):

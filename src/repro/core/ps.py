@@ -145,6 +145,18 @@ class PSApp:
         simulator.  ``u`` is the additive update sent to the server.
       loss: ``(x[d], locals) -> scalar`` global training objective, where
         ``locals`` is the stacked worker-local state.
+      loss_sum: ``(x[d], locals, worker_ids) -> (sums[B], count)``,
+        optional: the objective summed over each of a block of ``B``
+        workers' data (``locals`` leaves lead with the block's workers,
+        ``worker_ids`` their ids), each sum of ``count`` terms (a Python
+        int, the same for every worker), so that ``loss`` is every
+        worker's sum added in worker order (`fold_workers`) over ``count *
+        n_workers``.  The runtimes evaluate it on each worker shard's own
+        data, put the sums in their workers' slots of a ``[P]`` vector,
+        add the shards' vectors with one ``psum`` (exact: one shard fills
+        each slot) and fold it as ``loss`` does, so the objective's order
+        of additions is the same on every mesh.  Without it the runtimes
+        gather every worker's locals and call ``loss``.
     """
 
     name: str
@@ -154,6 +166,16 @@ class PSApp:
     local0: Any
     worker_update: Callable
     loss: Callable
+    loss_sum: Callable | None = None
+
+
+def fold_workers(sums) -> jax.Array:
+    """``[P]`` per-worker sums added in worker order, a left fold: the one
+    order every engine uses, whatever a mesh's reduction would do."""
+    total = sums[0]
+    for p in range(1, sums.shape[0]):
+        total = total + sums[p]
+    return total
 
 
 @jax.tree_util.register_dataclass

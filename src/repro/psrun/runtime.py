@@ -44,12 +44,20 @@ Per clock, inside ``shard_map`` (collectives annotated):
    advances ``cview`` eagerly for ESSP/async/VAP; SSP ignores pushes
    (pull-based);
 6. the clock's record (losses over the full data, staleness, deliveries)
-   becomes one row of the `Trace`.
+   becomes one row of the `Trace`: the table assembles with an
+   ``all_gather`` over ``"model"``, reader 0's view reaches every worker
+   shard through a ``psum`` over the worker axes, and each shard
+   evaluates the objective over each of its own workers' data
+   (``app.loss_sum``); one ``psum`` over the worker axes hands every
+   shard all workers' sums, added in worker order as ``app.loss`` adds
+   them (`core.ps.fold_workers`).
 
 Each step runs under a ``jax.named_scope``: ``psrun.enforce`` (1, with
 the suffix norms), ``psrun.view`` (2), ``psrun.update`` (3),
 ``psrun.push`` (4 and 5) and ``psrun.record`` (6), so a profiler trace
-can charge every device op of the clock to its stage.
+can charge every device op of the clock to its stage; each collective
+runs under a nested ``exchange`` scope, which splits a stage's
+collective time from its compute.
 
 RNG and arithmetic mirror ``core.ps.simulate`` *exactly* (same key splits,
 same per-coordinate reduction orders), which is what makes the simulator an
@@ -70,6 +78,7 @@ bit for bit (``checkpoint.io.save_runtime`` round-trips it through disk —
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any
 
 import jax
@@ -82,7 +91,7 @@ from ..comm import wire
 from ..core.consistency import ConsistencyConfig
 from ..core.delays import ChurnSchedule, churn_live, churn_rates, \
     delivery_matrix, pod_of, staleness_bound_matrix
-from ..core.ps import PSApp, Trace, enforce_vap
+from ..core.ps import PSApp, Trace, enforce_vap, fold_workers
 from ..kernels import ops
 from ..kernels.ref import RING_EMPTY, RING_INVALID
 from ..launch.mesh import make_ps_mesh
@@ -163,8 +172,7 @@ def _local_fixed(app: PSApp) -> bool:
     """Whether ``app.worker_update`` hands its worker-local state back
     unchanged (MF's ratings, read only by the loss).  The runtime then
     keeps that state out of the scan's carry and out of the program's
-    outputs, gathers it across worker shards once a segment, not once a
-    clock, and returns the arrays it was given, placed once with the
+    outputs, and returns the arrays it was given, placed once with the
     program's input sharding: a segment neither carries, moves nor writes
     out a copy of it."""
     one = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
@@ -293,18 +301,37 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                                   in_axes=(0, 0, 0, None, 0))
 
         # Each stage of the clock runs under a named scope ``psrun.<stage>``
-        # (enforce, view, update, push, record).  The names reach only the
-        # HLO ``op_name`` metadata, which a profiler trace keeps per device
-        # op (``chipbench/stages.py`` reads it); the arithmetic is unchanged.
-        def gather_workers(tree):
-            return jax.tree_util.tree_map(
-                lambda x: jax.lax.all_gather(x, worker_axes, axis=0,
-                                             tiled=True), tree)
+        # (enforce, view, update, push, record), and each collective in it
+        # under a nested ``exchange``.  The names reach only the HLO
+        # ``op_name`` metadata, which a profiler trace keeps per device op
+        # (``chipbench/stages.py`` reads it); the arithmetic is unchanged.
+        def exchange():
+            return jax.named_scope("exchange")
+
+        def losses(xs, local):
+            """``app``'s objective at each of ``xs`` (full ``[d]`` vectors):
+            each worker shard sums it over each of its own workers' data
+            (``app.loss_sum``) into those workers' slots of a ``[P]``
+            vector; one ``psum`` of the vectors (adding zeros: exact) hands
+            every shard all of them, folded in worker order as
+            ``app.loss`` folds them.  An app without ``loss_sum`` gets
+            every worker's locals gathered instead."""
+            if app.loss_sum is None:
+                with exchange():
+                    local_all = jax.tree_util.tree_map(
+                        lambda a: jax.lax.all_gather(a, worker_axes, axis=0,
+                                                     tiled=True), local)
+                return [app.loss(x, local_all) for x in xs]
+            parts, counts = zip(*(app.loss_sum(x, local, worker_ids)
+                                  for x in xs))
+            slots = jnp.zeros((len(xs), P), f32).at[:, worker_ids].set(
+                jnp.stack(parts))
+            with exchange():
+                sums = jax.lax.psum(slots, worker_axes)  # analysis: ignore[unmasked-gather] -- per-worker loss sums, not producer updates; dead workers' frozen locals count, as in the oracle
+            return [fold_workers(sums[i]) / (n * P)
+                    for i, n in enumerate(counts)]
 
         local_in = local
-        if local_fixed:
-            with jax.named_scope("psrun.record"):
-                locals_fixed_all = gather_workers(local)
 
         def step(carry, c):
             if obs_enabled:
@@ -347,8 +374,9 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
 
                 # global per-producer suffix-aggregate inf-norms: local block
                 # norms, max-reduced over the owning shards.
-                norms = jax.lax.pmax(
-                    ops.vap_suffix_norms(uring, uclock, c), "model")  # [W+1, P]
+                norms = ops.vap_suffix_norms(uring, uclock, c)
+                with exchange():
+                    norms = jax.lax.pmax(norms, "model")           # [W+1, P]
 
                 # --- 1. pre-read consistency enforcement (blocking fetches) --
                 if cfg.model == "bsp":
@@ -390,8 +418,9 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 staleness = cview - c                              # [Pl, P]
 
                 kcur = jnp.clip(c - 1 - cview, 0, W)               # [Pl, P]
-                intransit_inf = jax.lax.pmax(
-                    jnp.max(norms[kcur, producer_ids[None, :]]), worker_axes)
+                intransit_inf = jnp.max(norms[kcur, producer_ids[None, :]])
+                with exchange():
+                    intransit_inf = jax.lax.pmax(intransit_inf, worker_axes)
 
             with jax.named_scope("psrun.view"):
                 # --- 2. materialize views: shard-local, then assemble --------
@@ -410,8 +439,9 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                                                cv_xpod))              # [Pl, dl]
                 else:
                     views_l = ops.ring_view(base, uring, uclock, cview)
-                views = jax.lax.all_gather(views_l, "model", axis=1,
-                                           tiled=True)[:, :d]        # [Pl, d]
+                with exchange():
+                    views = jax.lax.all_gather(views_l, "model", axis=1,
+                                               tiled=True)[:, :d]    # [Pl, d]
 
             with jax.named_scope("psrun.update"):
                 # --- 3. worker computation (this shard's workers only) -------
@@ -439,7 +469,9 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 # pod axis it is the eager cross-pod delta channel (one fresh
                 # [P, d] update set per clock keeps every pod replica's ring
                 # reconciled; visibility stays gated by cview above).
-                u_all = jax.lax.all_gather(u_l, worker_axes, axis=0, tiled=True)
+                with exchange():
+                    u_all = jax.lax.all_gather(u_l, worker_axes, axis=0,
+                                               tiled=True)
                 # norm on the gathered [P, d] — the oracle's operand shape, so
                 # XLA emits the same reduction and the floats match bit-for-bit
                 u_l2 = jnp.linalg.norm(u_all, axis=-1)
@@ -466,8 +498,9 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                     # pack itself is elementwise on the local shard.
                     acc = cst["acc"] + u_blk
                     delta = acc + cst["res"]                     # [P, dl]
-                    delta_full = jax.lax.all_gather(
-                        delta, "model", axis=1, tiled=True)[:, :d]
+                    with exchange():
+                        delta_full = jax.lax.all_gather(
+                            delta, "model", axis=1, tiled=True)[:, :d]
                     thresh = comm.row_threshold(delta_full, cfg.topk_frac)
                     scale = comm.quant_scale(delta_full, cfg.quant)
                     wire_u, resid = ops.delta_pack(delta, thresh, scale,
@@ -551,7 +584,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                         cview = jnp.where(delivered, c, cview)
 
             with jax.named_scope("psrun.record"):
-                # --- 6. record (gathered so losses match the oracle exactly) --
+                # --- 6. record: the table and reader 0's view, the losses
+                # per worker shard (`losses`) ------------------------------
                 if wired:
                     x_ref = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
                         uring * (uclock[:, None, None] > RING_INVALID),
@@ -560,13 +594,15 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                     x_ref = base + jnp.sum(
                         uring * (uclock[:, None, None] > RING_INVALID),
                         axis=(0, 1))
-                x_ref = jax.lax.all_gather(x_ref, "model", tiled=True)[:d]
-                locals_all = (locals_fixed_all if local_fixed
-                              else gather_workers(local))
-                views_all = jax.lax.all_gather(  # analysis: ignore[unmasked-gather] -- record-side gather of reader *views* for trace metrics, not a producer reduction; dead readers' rows are inert (their cview froze) and the oracle gathers identically
-                    views, worker_axes, axis=0, tiled=True)
-                out = dict(loss_ref=app.loss(x_ref, locals_all),
-                           loss_view=app.loss(views_all[0], locals_all),
+                # reader 0's view, sent from the shard that holds it to every
+                # shard: the others add zeros (exact)
+                sent = jnp.where(di == 0, views[0], 0.0)
+                with exchange():
+                    x_ref = jax.lax.all_gather(x_ref, "model", tiled=True)
+                    view0 = jax.lax.psum(sent, worker_axes)  # analysis: ignore[unmasked-gather] -- reader 0's view sent to every shard, not a producer reduction; the oracle reads views[0] alike
+                x_ref = x_ref[:d]
+                loss_ref, loss_view = losses((x_ref, view0), local)
+                out = dict(loss_ref=loss_ref, loss_view=loss_view,
                            staleness=staleness, forced=forced,
                            delivered=delivered,
                            u_l2=u_l2, intransit_inf=intransit_inf,
@@ -574,7 +610,7 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                            live=live_now if churned
                            else jnp.ones((P,), bool))
                 if record_views:
-                    out["views0"] = views_all[0]
+                    out["views0"] = view0
                 if obs_enabled:
                     # shard-local fold of this clock's step values; shards
                     # merge once after the scan (device_reduce), not per clock
@@ -710,20 +746,34 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         return (replace(trace, locals_final=state.local),
                 replace(new, local=state.local))
 
-    def init_state(seed) -> PSState:
-        """Clock-0 state for ``seed`` (the simulator's initial conditions,
-        in the runtime's padded layout)."""
-        return PSState(
+    placed = {k: jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec), v,
+        is_leaf=lambda x: isinstance(x, P_))
+        for k, v in state_specs.items() if v is not None and k != "local"}
+
+    @partial(jax.jit, out_shardings=placed)
+    def fresh(x0, rng):
+        state = dict(
             clock=jnp.zeros((), jnp.int32),
-            base=jnp.pad(app.x0.astype(f32), (0, dpad - d)),
+            base=jnp.pad(x0.astype(f32), (0, dpad - d)),
             uring=jnp.zeros((W, P, dpad), f32),
             uclock=jnp.full((W,), RING_EMPTY, jnp.int32),
-            cview=jnp.full((P, P), -1, jnp.int32),
-            local=place_local(app.local0),
-            rng=jax.random.PRNGKey(seed),
-            comm=({**comm.init_state(W, P, dpad, G),
-                   **wire.init_wire_state(P, dpad)} if faulted
-                  else comm.init_state(W, P, dpad, G)) if wired else None)
+            cview=jnp.full((P, P), -1, jnp.int32), rng=rng)
+        if wired:
+            state["comm"] = ({**comm.init_state(W, P, dpad, G),
+                              **wire.init_wire_state(P, dpad)} if faulted
+                             else comm.init_state(W, P, dpad, G))
+        return state
+
+    def init_state(seed) -> PSState:
+        """Clock-0 state for ``seed`` (the simulator's initial conditions,
+        in the runtime's padded layout), made where the program takes it:
+        every leaf laid out with the program's input sharding, so the
+        first segment runs the program every later one runs and no chip
+        holds more than its shard of the ring."""
+        made = fresh(app.x0, jax.random.PRNGKey(seed))
+        return PSState(local=jax.device_put(app.local0, local_shardings),
+                       comm=made.pop("comm", None), **made)
 
     def _norm_cfg(cfg_run: ConsistencyConfig | None) -> ConsistencyConfig:
         c = cfg if cfg_run is None else cfg_run

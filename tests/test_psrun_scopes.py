@@ -85,14 +85,16 @@ def test_kernels_run_in_their_stages(app):
 
 def test_mf_objective_kernel_runs_in_record():
     """An MF app built on a Pallas backend evaluates its objective with the
-    ``mf_sse`` kernel, twice a clock (the table and worker 0's view), in
-    ``psrun.record`` and in no other stage."""
+    ``mf_sse`` kernel (vmapped over the workers), twice a clock (the table
+    and worker 0's view), in ``psrun.record`` and in no other stage.  Each
+    worker's rows fold into the kernel's sublane groups of 8."""
     ops.set_backend("pallas_interpret")
     try:
-        dense = make_mf_app(MFConfig(n_rows=16, n_cols=12, rank=4,
+        dense = make_mf_app(MFConfig(n_rows=32, n_cols=12, rank=4,
                                      n_workers=4, batch=8))
         names = _op_names(dense, CONFIGS["essp"])
     finally:
         ops.set_backend("auto")
-    stages = {_stage(n) for n in names if "mf_sse" in n.split("/")}
+    kernel = {"mf_sse", "vmap(mf_sse)"}
+    stages = {_stage(n) for n in names if kernel & set(n.split("/"))}
     assert stages == {"record"}
