@@ -16,8 +16,7 @@ upstream by ``comm.substrate.row_threshold`` / ``quant_scale`` — a sort is
 not kernel material), and ``quant`` is static: each format compiles its
 own elementwise body.
 
-Layout mirrors `ps_view.py`: the last axis is blocked at a multiple of 128
-lanes, the sublane axis is the worker count P (small; Mosaic pads), and
+The last axis is blocked at a multiple of 128 lanes, the sublane axis is the worker count P (small; Mosaic pads), and
 the grid is 1-D over d-blocks.  Verified against the jnp reference under
 ``interpret=True`` by ``tests/test_comm.py``.
 """

@@ -6,9 +6,10 @@ under ``interpret=True``.  Selection can be forced with
 ``set_backend("pallas"|"pallas_interpret"|"ref")`` (kernel tests and
 benchmarks).
 
-On a Pallas backend nothing falls back in silence: the PS kernels pad the
-lane axis ``d`` up to the kernel block (zeros change no sum, inf-norm or
-pack) and slice the result, and a shape no kernel supports raises.
+On a Pallas backend nothing falls back in silence: the ring kernels take
+any lane width ``d`` (their last block is ragged), ``delta_pack`` pads it
+up to 128 lanes (zeros change no pack) and slices the result, and a shape
+no kernel supports raises.
 The kernels' ``pallas_call``s carry ``name=`` (``ring_view``,
 ``vap_suffix_norms``, ``delta_pack``, ``mf_sse``, ``flash_attention``): a
 compiled program that took one holds its name in its ops' ``op_name``.
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 from . import ref
 
 _BACKEND = "auto"
-_LANE = 128                      # kernel block on the lane axis
+_LANE = 128                      # delta_pack's block on the lane axis
 
 # Perf toggles (see EXPERIMENTS.md §Perf): static_causal skips fully-masked
 # causal KV blocks in full-sequence attention (positions are arange there).
@@ -121,12 +122,9 @@ def ring_view(base, uring, uclock, cview):
     if interpret is None:
         return ref.ring_view(base, uring, uclock, cview)
     from . import ps_view
-    d = uring.shape[-1]
-    uring_p = _pad_lanes(uring)
-    if not ps_view.supported(uring_p):
+    if not ps_view.supported(uring):
         _unsupported("ring_view", uring.shape, "needs P <= 128, W <= 64")
-    return ps_view.ring_view(_pad_lanes(base), uring_p, uclock, cview,
-                             interpret=interpret)[:, :d]
+    return ps_view.ring_view(base, uring, uclock, cview, interpret=interpret)
 
 
 def vap_suffix_norms(uring, uclock, c):
@@ -135,11 +133,10 @@ def vap_suffix_norms(uring, uclock, c):
     if interpret is None:
         return ref.vap_suffix_norms(uring, uclock, c)
     from . import ps_view
-    uring_p = _pad_lanes(uring)
-    if not ps_view.supported(uring_p):
+    if not ps_view.supported(uring):
         _unsupported("vap_suffix_norms", uring.shape,
                      "needs P <= 128, W <= 64")
-    return ps_view.vap_suffix_norms(uring_p, uclock, c, interpret=interpret)
+    return ps_view.vap_suffix_norms(uring, uclock, c, interpret=interpret)
 
 
 def delta_pack(delta, thresh, scale, quant: str = "f32"):

@@ -310,8 +310,9 @@ def test_attention_custom_vjp_matches_ref_grad():
 
 @pytest.mark.parametrize("d", [200, 128, 300])
 def test_ps_ops_pad_unaligned_width(d):
-    """The Pallas branch pads the lane axis to the kernel block and slices
-    back, so every width takes the kernel and matches the reference."""
+    """Every width takes the kernel and matches the reference: the ring
+    kernels end on a ragged block, ``delta_pack``'s branch pads the lane
+    axis to 128 and slices back."""
     from repro.comm import substrate as comm
     rng = np.random.default_rng(d)
     W, P, R, c = 5, 8, 4, 7
@@ -335,6 +336,27 @@ def test_ps_ops_pad_unaligned_width(d):
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_ring_ops_do_not_pad():
+    """On a Pallas backend the ring kernels take the ring at its width: the
+    traced program holds the kernel and no pad of its operands or slice of
+    its result."""
+    W, P, d = 5, 8, 1000
+    uring = jnp.ones((W, P, d))
+    uclock = jnp.asarray([6, 5, 4, 3, 2], jnp.int32)
+    cview = jnp.full((P, P), 6, jnp.int32)
+    ops.set_backend("pallas_interpret")
+    try:
+        jaxprs = [jax.make_jaxpr(ops.ring_view)(jnp.zeros((d,)), uring,
+                                                 uclock, cview),
+                  jax.make_jaxpr(ops.vap_suffix_norms)(uring, uclock, 7)]
+    finally:
+        ops.set_backend("auto")
+    for jaxpr in jaxprs:
+        prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+        assert "pallas_call" in prims
+        assert not prims & {"pad", "slice", "dynamic_slice"}, prims
 
 
 def test_kernel_name_marks_pallas_programs():

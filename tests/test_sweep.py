@@ -147,25 +147,131 @@ def _ring_inputs(W=7, P=8, d=256, c=13, seed=0):
     return base, uring, uclock, cview, jnp.int32(c)
 
 
-@pytest.mark.parametrize("shape", [(7, 8, 256), (3, 4, 128), (12, 16, 512)])
+# d not a multiple of 128 (nor of the 256-lane block): a ragged last block
+RAGGED = [(5, 8, 1000), (2, 8, 300), (7, 4, 1153)]
+KERNEL_SHAPES = [(7, 8, 256), (3, 4, 128), (12, 16, 512)] + RAGGED
+
+
+@pytest.mark.parametrize("shape", KERNEL_SHAPES)
 def test_ring_view_kernel_matches_ref(shape):
+    """At `block_lanes`' block (one step at these widths) and at 256 lanes
+    (several steps, the last ragged where 256 does not divide d)."""
     W, P, d = shape
     base, uring, uclock, cview, _ = _ring_inputs(W, P, d)
     want = ref.ring_view(base, uring, uclock, cview)
-    got = ps_view.ring_view(base, uring, uclock, cview, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    for block_d in (None, 256):
+        got = ps_view.ring_view(base, uring, uclock, cview, block_d=block_d,
+                                interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5, err_msg=str(block_d))
 
 
-@pytest.mark.parametrize("shape", [(7, 8, 256), (3, 4, 128), (12, 16, 512)])
+@pytest.mark.parametrize("shape", KERNEL_SHAPES)
 def test_vap_suffix_norms_kernel_matches_ref(shape):
     W, P, d = shape
     _, uring, uclock, _, c = _ring_inputs(W, P, d)
     want = ref.vap_suffix_norms(uring, uclock, c)
-    got = ps_view.vap_suffix_norms(uring, uclock, c, interpret=True)
-    assert got.shape == (W + 1, P)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+    for block_d in (None, 256):
+        got = ps_view.vap_suffix_norms(uring, uclock, c, block_d=block_d,
+                                       interpret=True)
+        assert got.shape == (W + 1, P)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6, err_msg=str(block_d))
+
+
+def _pad_128(x):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, (-x.shape[-1]) % 128)])
+
+
+def _ragged_inputs(W, P, d, peak_last):
+    """`_ring_inputs`, every slot holding one of the newest W clocks; with
+    ``peak_last`` each slot's largest |value| sits in the last lane < d."""
+    base, uring, _, cview, c = _ring_inputs(W, P, d)
+    uclock = jnp.arange(int(c) - 1, int(c) - 1 - W, -1, dtype=jnp.int32)
+    if peak_last:
+        uring = uring.at[:, :, d - 1].set(
+            1e3 * (1.0 + jnp.arange(W * P, dtype=jnp.float32).reshape(W, P)))
+    return base, uring, uclock, cview, c
+
+
+# Blocks of 128, 256 and 1024 lanes and `block_lanes`' one block.  The
+# interpreter reads lanes past d as NaN, so a lane the kernel failed to
+# mask or to drop would show.
+BLOCKS = (128, 256, 1024, None)
+
+
+@pytest.mark.parametrize("peak_last", [False, True])
+@pytest.mark.parametrize("shape", RAGGED)
+def test_vap_suffix_norms_bit_equal_across_blocks(shape, peak_last):
+    """Any block gives the norms bit for bit, and they are the norms of the
+    ring padded with zeros to 128 lanes at a 128-lane block (how the
+    kernel was called before its last block could be ragged)."""
+    W, P, d = shape
+    _, uring, uclock, _, c = _ragged_inputs(W, P, d, peak_last)
+    want = np.asarray(ps_view.vap_suffix_norms(_pad_128(uring), uclock, c,
+                                               block_d=128, interpret=True))
+    for block_d in BLOCKS:
+        got = ps_view.vap_suffix_norms(uring, uclock, c, block_d=block_d,
+                                       interpret=True)
+        assert np.array_equal(np.asarray(got), want), block_d
+    # the kernel's arithmetic in float32 numpy: per k, every slot's masked
+    # add in slot order, then the lane max of |suffix|
+    ring, clocks = np.asarray(uring), np.asarray(uclock)
+    suffix = np.zeros((P, d), np.float32)
+    peaks = [np.zeros(P, np.float32)]
+    for k in range(1, W + 1):
+        for w in range(W):
+            suffix = suffix + np.where(clocks[w] == int(c) - k, ring[w],
+                                       np.float32(0))
+        peaks.append(np.abs(suffix).max(axis=-1))
+    assert np.array_equal(want, np.stack(peaks))
+    if peak_last:                       # the last lane holds every norm
+        np.testing.assert_array_equal(
+            want[1:], np.abs(np.cumsum(np.asarray(uring[:, :, d - 1]), 0)))
+
+
+@pytest.mark.parametrize("peak_last", [False, True])
+@pytest.mark.parametrize("shape", RAGGED)
+def test_ring_view_bit_equal_across_blocks(shape, peak_last):
+    """As for the norms: every block gives the padded ring's views at 128
+    lanes, bit for bit (each lane's dot does not depend on the block)."""
+    W, P, d = shape
+    base, uring, uclock, cview, _ = _ragged_inputs(W, P, d, peak_last)
+    want = np.asarray(ps_view.ring_view(_pad_128(base), _pad_128(uring),
+                                        uclock, cview, block_d=128,
+                                        interpret=True))[:, :d]
+    for block_d in BLOCKS:
+        got = ps_view.ring_view(base, uring, uclock, cview, block_d=block_d,
+                                interpret=True)
+        assert got.shape == (P, d)
+        assert np.array_equal(np.asarray(got), want), block_d
+
+
+# (W, P, R, d) of the cells' rings: ESSP(3) and BSP over MF at rank 100
+# with 32,768 users and 17,770 items on one chip, and ESSP(3) over 131,072
+# users split over a mesh axis of 2 with 4 readers a data shard
+CELL_RINGS = [(5, 8, 8, 5_053_800), (2, 8, 8, 5_053_800),
+              (5, 8, 4, 7_442_100)]
+
+
+@pytest.mark.parametrize(("W", "P", "R", "d"),
+                         CELL_RINGS + [(7, 8, 8, 256), (3, 4, 4, 128),
+                                       (12, 16, 16, 512), (5, 8, 8, 1000),
+                                       (7, 4, 4, 1153)])
+def test_block_lanes(W, P, R, d):
+    """A multiple of 128 lanes up to d rounded up to 128, whose
+    double-buffered blocks fit the budget: hundreds of steps or fewer a
+    call at the cells' widths, one at the tests' small ones."""
+    padded = -(-d // 128) * 128
+    for readers in (R, 0):              # ring_view; vap_suffix_norms
+        block = ps_view.block_lanes(W, P, readers, d)
+        assert block % 128 == 0 and 128 <= block <= padded
+        rows = W * (-(-P // 8) * 8)
+        if readers:
+            rows += 8 + -(-readers // 8) * 8
+        assert 2 * 4 * rows * block <= ps_view.VMEM_BUDGET
+        steps = -(-d // block)
+        assert steps == 1 if d <= 2048 else 10 <= steps <= 1000
 
 
 def test_suffix_norms_semantics():

@@ -4,8 +4,9 @@ compiler for a v5e chip that is described, not attached.
 Interpret mode cannot see what Mosaic (TPU's Pallas compiler) refuses:
 block shapes off the (8, 128) tiling, too much fast memory, an
 unpartitionable kernel.  These tests compile each kernel at real widths —
-the PS kernels at the padded width of MF at rank 100 over 16,384 users and
-17,770 items, MF's dense objective over 32,768 users, flash attention at
+the PS kernels at the width of MF at rank 100 over 16,384 users and
+17,770 items and at the cells' unpadded ring widths, MF's dense objective
+over 32,768 users, flash attention at
 Qwen3-0.6B's widths — and assert that the
 program holds the kernel (``tpu_custom_call``) under its name.  The kernels are called
 with ``interpret=False`` directly, because ``ops.get_backend()`` sees the
@@ -32,6 +33,11 @@ from repro.kernels import mf_sse, ops, ps_view
 # MF at rank 100 x (16,384 users + 17,770 items), padded to 128 lanes.
 D_MF = 3_415_424
 W_RING, P_WORKERS = 5, 8            # essp(3) ring window, 8 workers
+# The cells' rings (W, P, readers, d), unpadded: ESSP(3) and BSP over MF at
+# rank 100 x (32,768 users + 17,770 items) on one chip; ESSP(3) over
+# 131,072 users split over a mesh axis of 2, 4 readers a data shard.
+CELL_RINGS = [(5, 8, 8, 5_053_800), (2, 8, 8, 5_053_800),
+              (5, 8, 4, 7_442_100)]
 # MF's dense objective: 32,768 users x 17,770 items (padded to 128 lanes)
 N_MF, M_PAD_MF, K_MF = 32_768, 17_792, 100
 QWEN3 = dict(B=4, S=512, H=16, Hkv=8, Dh=128)
@@ -89,6 +95,28 @@ def test_vap_suffix_norms_compiles(one_chip):
         lambda u, uc, c: ps_view.vap_suffix_norms(u, uc, c, interpret=False),
         _shape(one_chip, (W_RING, P_WORKERS, D_MF)),
         _shape(one_chip, (W_RING,), jnp.int32),
+        _shape(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize(("W", "P", "R", "d"), CELL_RINGS)
+def test_ring_view_compiles_at_cell_width(one_chip, W, P, R, d):
+    """The ring at its unpadded width, in `block_lanes`' blocks."""
+    _assert_kernel(
+        "ring_view",
+        lambda b, u, uc, cv: ps_view.ring_view(b, u, uc, cv, interpret=False),
+        _shape(one_chip, (d,)),
+        _shape(one_chip, (W, P, d)),
+        _shape(one_chip, (W,), jnp.int32),
+        _shape(one_chip, (R, P), jnp.int32))
+
+
+@pytest.mark.parametrize(("W", "P", "R", "d"), CELL_RINGS)
+def test_vap_suffix_norms_compiles_at_cell_width(one_chip, W, P, R, d):
+    _assert_kernel(
+        "vap_suffix_norms",
+        lambda u, uc, c: ps_view.vap_suffix_norms(u, uc, c, interpret=False),
+        _shape(one_chip, (W, P, d)),
+        _shape(one_chip, (W,), jnp.int32),
         _shape(one_chip, (), jnp.int32))
 
 
