@@ -8,15 +8,18 @@ seeds they happen to run; this module instead
    ``core.delays.staleness_bound_matrix`` (symbolically evaluating its
    straight-line integer algebra, with and without the
    ``cfg.comm_active`` widening branch),
-2. **extracts** the clock-update dataflow of each Trace producer — the
-   enforcement trigger ``forced = cview < (c - s_eff - 1)``, the
+2. **extracts** the clock-update dataflow the Trace producers share —
+   the enforcement trigger ``forced = cview < (c - s_eff - 1)`` and the
    refresh targets (``c - 1`` intra-pod / unwired,
    ``comm.shipped_through(c, agg_clocks)`` on the wired cross-pod
-   channel) and the delivery targets (``c`` /
-   ``comm.shipped_end(c, agg_clocks)``) — from ``core/ps.py`` and
-   ``psrun/runtime.py``, and verifies ``pods/runtime.py`` delegates its
-   clock step to the psrun body (class ``PodsRuntime(PSRuntime)`` with no
-   own enforcement code), and
+   channel) from ``enforce``, the delivery targets (``c`` /
+   ``comm.shipped_end(c, agg_clocks)``) from ``deliver``, both in
+   ``core/ps.py`` — and verifies the other producers delegate:
+   ``psrun/runtime.py`` calls ``reader_rows``, ``enforce`` and
+   ``deliver``, and
+   ``pods/runtime.py`` runs the psrun body (class
+   ``PodsRuntime(PSRuntime)``), neither with enforcement code of its
+   own, and
 3. **model-checks** the extracted transition system exhaustively over a
    grid of small ``(T, P, s, s_xpod, agg_clocks)`` configurations,
    including single reader-outage (churn) windows: per channel, the
@@ -52,7 +55,8 @@ from __future__ import annotations
 
 import ast
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
+from pathlib import Path
 
 from .base import RULE_DOCS, Finding, dotted
 
@@ -64,6 +68,8 @@ RULE_DOCS.update({
 })
 
 PRODUCER_FILES = ("core/ps.py", "psrun/runtime.py", "pods/runtime.py")
+POLICY_FILE = "core/ps.py"      # home of the shared enforce()/deliver()
+POLICY_PATH = Path(__file__).resolve().parents[1] / "core" / "ps.py"
 
 
 # --------------------------------------------------------------------------
@@ -262,38 +268,75 @@ def _refresh_lag(node) -> int | None:
     return None
 
 
-def extract_enforcement_from_source(source: str,
-                                    producer: str) -> EnforcementModel:
-    """Extract the SSP/ESSP enforcement dataflow from a producer module."""
+def _triggers(node) -> list:
+    """The offsets K of every ``forced = cview < (c - s_eff - K)``."""
+    return [k for n in ast.walk(node)
+            if isinstance(n, ast.Assign) and len(n.targets) == 1
+            and isinstance(n.targets[0], ast.Name)
+            and n.targets[0].id == "forced"
+            for k in [_match_trigger(n.value)] if k is not None]
+
+
+def _targets(fn, shipped: str) -> tuple:
+    """``(lag, routed, capped)`` of ``fn``'s ``x = jnp.where(cond, then,
+    other)`` targets: the ``c - LAG`` of ``jnp.where(forced, c - LAG,
+    cview)`` (else of the intra arm of the wired target), whether a
+    wired target routes through ``comm.<shipped>(c, agg)``, and whether
+    one caps it on ``cst["wire_tip"]`` (the faulted arm)."""
+    lag, routed, capped = None, False, False
+    for node in ast.walk(fn):
+        if not (isinstance(node, ast.Assign)
+                and isinstance(node.value, ast.Call)
+                and (dotted(node.value.func) or "").split(".")[-1] == "where"
+                and len(node.value.args) == 3):
+            continue
+        cond, then, _other = node.value.args
+        if isinstance(cond, ast.Name) and cond.id == "forced" \
+                and _refresh_lag(then) is not None:
+            lag = _refresh_lag(then)
+        if _calls_named(node.value, shipped):
+            routed = True
+            capped |= _caps_wire_tip(node.value)
+            if lag is None:
+                lag = _refresh_lag(then)
+    return lag, routed, capped
+
+
+def extract_enforcement_from_source(source: str, producer: str,
+                                    shared: EnforcementModel | None = None
+                                    ) -> EnforcementModel:
+    """Extract the SSP/ESSP enforcement dataflow from a producer module.
+
+    The module that defines ``enforce`` and ``deliver`` (`core/ps.py`) is
+    extracted: the trigger and refresh targets from ``enforce``, the
+    delivery targets from ``deliver``.  Any other producer must delegate
+    — call ``reader_rows``, ``enforce`` and ``deliver``, or subclass
+    ``PSRuntime`` — and gets the ``shared`` model (by default extracted
+    from this package's `core/ps.py`) under its own name."""
     tree = ast.parse(source)
+    fns = {n.name: n for n in tree.body if isinstance(n, ast.FunctionDef)}
+    home = fns.get("enforce")
+    triggers = _triggers(home) if home is not None else []
+    if len(_triggers(tree)) != len(triggers):
+        raise ExtractionError(
+            f"{producer}: re-implements enforcement outside the shared "
+            f"enforce() of {POLICY_FILE} — update the model checker")
+    calls = all(_calls_named(tree, f)
+                for f in ("reader_rows", "enforce", "deliver"))
+    if not calls and not any(   # PodsRuntime runs the psrun body
+            isinstance(node, ast.ClassDef)
+            and any((dotted(b) or "").split(".")[-1] == "PSRuntime"
+                    for b in node.bases) for node in ast.walk(tree)):
+        raise ExtractionError(
+            f"{producer}: does not run the shared clock policy "
+            f"(reader_rows, enforce, deliver) of {POLICY_FILE}")
+    if home is None:
+        if shared is None:
+            shared = extract_enforcement(str(POLICY_PATH), POLICY_FILE)
+        return replace(shared, producer=producer,
+                       delegate=POLICY_FILE if calls else "psrun/runtime.py")
 
-    # delegation: PodsRuntime subclasses PSRuntime and defines no
-    # enforcement of its own — its clock step IS the psrun body
-    for node in ast.walk(tree):
-        if isinstance(node, ast.ClassDef) \
-                and any(isinstance(b, (ast.Name, ast.Attribute))
-                        and (dotted(b) or "").split(".")[-1] == "PSRuntime"
-                        for b in node.bases):
-            if any(_match_trigger(n) is not None
-                   for n in ast.walk(node)):
-                raise ExtractionError(
-                    f"{producer}: delegating runtime re-implements "
-                    f"enforcement — update the model checker")
-            return EnforcementModel(
-                producer=producer, trigger_offset=1, refresh_lag=1,
-                xpod_refresh_shipped=True, delivery_shipped=True,
-                xpod_refresh_capped=True, delivery_capped=True,
-                delegate="psrun/runtime.py")
-
-    trigger = None
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name) \
-                and node.targets[0].id == "forced":
-            k = _match_trigger(node.value)
-            if k is not None:
-                trigger = k
-    if trigger is None:
+    if not triggers:
         raise ExtractionError(
             f"{producer}: no `forced = cview < (c - s_eff - K)` "
             f"enforcement trigger found")
@@ -305,40 +348,12 @@ def extract_enforcement_from_source(source: str,
             f"{producer}: `s_eff` is not derived from "
             f"staleness_bound_matrix — the declared bound is not the one "
             f"enforced")
-
-    # refresh/delivery targets: `cview = jnp.where(forced, c - K, cview)`
-    # on the unwired path; on the wired path the target routes through
-    # `tgt = jnp.where(in_pod, c - K, comm.shipped_through(c, agg))` (and
-    # delivery through comm.shipped_end) before the forced/delivered where
-    refresh_lag = None
-    xpod_refresh_shipped = False
-    delivery_shipped = False
-    xpod_refresh_capped = False
-    delivery_capped = False
-    for node in ast.walk(tree):
-        if not (isinstance(node, ast.Assign)
-                and isinstance(node.value, ast.Call)):
-            continue
-        d = dotted(node.value.func)
-        if not d or d.split(".")[-1] != "where":
-            continue
-        args = node.value.args
-        if len(args) != 3:
-            continue
-        cond, then, _other = args
-        cond_name = cond.id if isinstance(cond, ast.Name) else None
-        if cond_name == "forced" and _refresh_lag(then) is not None:
-            refresh_lag = _refresh_lag(then)
-        if _calls_named(node.value, "shipped_through"):
-            xpod_refresh_shipped = True
-            if _caps_wire_tip(node.value):
-                xpod_refresh_capped = True   # the faulted branch's tgt
-            if refresh_lag is None and _refresh_lag(then) is not None:
-                refresh_lag = _refresh_lag(then)   # the intra arm of tgt
-        if _calls_named(node.value, "shipped_end"):
-            delivery_shipped = True
-            if _caps_wire_tip(node.value):
-                delivery_capped = True
+    if "deliver" not in fns:
+        raise ExtractionError(f"{producer}: no deliver() beside enforce()")
+    refresh_lag, xpod_refresh_shipped, xpod_refresh_capped = _targets(
+        fns["enforce"], "shipped_through")
+    _, delivery_shipped, delivery_capped = _targets(fns["deliver"],
+                                                    "shipped_end")
     if refresh_lag is None:
         raise ExtractionError(
             f"{producer}: no forced-refresh target "
@@ -362,7 +377,7 @@ def extract_enforcement_from_source(source: str,
             f"{producer}: no faulted delivery caps comm.shipped_end on "
             f"cst[\"wire_tip\"]")
     return EnforcementModel(
-        producer=producer, trigger_offset=trigger,
+        producer=producer, trigger_offset=triggers[0],
         refresh_lag=refresh_lag,
         xpod_refresh_shipped=xpod_refresh_shipped,
         delivery_shipped=delivery_shipped,
@@ -573,7 +588,8 @@ def model_check(bound_model: BoundModel, enf: EnforcementModel,
 
 def check_repo(modules) -> list:
     """Run extraction + model check when the scan set contains the three
-    Trace producers; silently skip when it does not (fixture scans)."""
+    Trace producers; silently skip when it does not (fixture scans).  The
+    policy module is model-checked; the others must delegate to it."""
     by_suffix = {}
     delays = None
     for mod in modules:
@@ -584,20 +600,21 @@ def check_repo(modules) -> list:
             delays = mod
     if delays is None or len(by_suffix) != len(PRODUCER_FILES):
         return []
-    findings = []
     try:
         bound_model = extract_bound_model_from_source(delays.source)
     except ExtractionError as e:
         return [Finding("staleness-extract", delays.rel, 1, str(e))]
-    for suffix in PRODUCER_FILES:
-        mod = by_suffix[suffix]
+    policy, *delegates = (by_suffix[s] for s in PRODUCER_FILES)
+    try:
+        shared = extract_enforcement_from_source(policy.source, POLICY_FILE)
+    except ExtractionError as e:
+        return [Finding("staleness-extract", policy.rel, 1, str(e))]
+    findings = [Finding("staleness-contract", policy.rel, 1, str(ce))
+                for ce in model_check(bound_model, shared)]
+    for suffix, mod in zip(PRODUCER_FILES[1:], delegates):
         try:
-            enf = extract_enforcement_from_source(mod.source, suffix)
+            extract_enforcement_from_source(mod.source, suffix, shared)
         except ExtractionError as e:
             findings.append(Finding("staleness-extract", mod.rel, 1,
                                     str(e)))
-            continue
-        for ce in model_check(bound_model, enf):
-            findings.append(Finding("staleness-contract", mod.rel, 1,
-                                    str(ce)))
     return findings

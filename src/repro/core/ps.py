@@ -100,21 +100,23 @@ consumer (``core.staleness``, ``core.theory``, ``core.valuebound``,
   ``("pod","data","model")`` mesh (replicated parameter shards per pod,
   cross-pod reconciliation), sharing the clock-step machinery with psrun.
 
-All fill every `Trace` field with the clock axis leading, derive all
-randomness from the same key stream (``split(rng, 3)`` per clock; worker
-keys ``split(k_upd, P)``; delivery from ``k_net``), and keep identical
-per-coordinate reduction orders — which is why a seeded BSP run is
-bit-identical between them, and SSP/ESSP runs are too (asserted by
-``psrun.validate.cross_validate`` since the bit-match was promoted into
-the contract; VAP agrees to fusion tolerance with exactly-equal
-decisions).  Anything that changes a `Trace` field, the key derivation, or
-a reduction order here must be mirrored in ``psrun/runtime.py`` —
+The clock step's consistency policy is written once, below, over reader
+rows: ``simulate`` calls it on the full ``[P, P]`` channel matrix, the
+runtimes on each worker shard's rows.  All fill every `Trace` field with
+the clock axis leading, derive all randomness from the same key stream
+(``split(rng, 3)`` per clock; worker keys ``split(k_upd, P)``; delivery
+from ``k_net``), and keep identical per-coordinate reduction orders —
+which is why a seeded BSP run is bit-identical between them, and SSP/ESSP
+runs are too (asserted by ``psrun.validate.cross_validate`` since the
+bit-match was promoted into the contract; VAP agrees to fusion tolerance
+with exactly-equal decisions).  A change to the key derivation or to a
+reduction order of the data plane must keep the engines in step —
 `tests/test_psrun.py` and `tests/test_pods.py` enforce the contract.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -126,7 +128,7 @@ from ..kernels.ref import RING_EMPTY, RING_INVALID
 from ..obs import metrics as obsm
 from .consistency import ConsistencyConfig
 from .delays import ChurnSchedule, churn_live, churn_rates, \
-    delivery_matrix, pod_of, same_pod_mask, staleness_bound_matrix
+    delivery_matrix, pod_of, staleness_bound_matrix
 
 
 @dataclass
@@ -210,11 +212,6 @@ class Trace:
     #                            stack/compare exactly as before
 
 
-def _delivery(rng, cfg: ConsistencyConfig, P: int, rates=None):
-    """Sample the end-of-clock delivery matrix (see core/delays.py)."""
-    return delivery_matrix(rng, cfg, P, rates)
-
-
 def enforce_vap(cfg: ConsistencyConfig, c, cview, norms, W: int):
     """Force delivery of oldest in-transit updates so that the per-producer
     aggregated in-transit update satisfies ``||.||_inf <= v_t`` (paper
@@ -239,6 +236,292 @@ def enforce_vap(cfg: ConsistencyConfig, c, cview, norms, W: int):
     required = c - 1 - kbest
     forced = cview < required
     return jnp.maximum(cview, required), forced
+
+
+# --------------------------------------------------------------------------
+# The clock step's consistency policy, written once over *reader rows*:
+# ``cview`` and every ``[R, P]`` mask below are the full ``[P, P]`` channel
+# matrix in `simulate` and a worker shard's rows ``[Pl, P]`` in the
+# runtimes (`repro.psrun`, `repro.pods`), which wrap these calls in their
+# data plane (sharding, collectives, the RNG row slices).  ``cst`` is the
+# comm-substrate state (`repro.comm`), None on the dense wire; under a
+# lossy wire it also holds the ARQ leaves (`comm.wire`).  ``live_rows``
+# (the readers' liveness) and ``live_now`` (all producers') are None
+# without a `ChurnSchedule`.
+# --------------------------------------------------------------------------
+
+class Rows(NamedTuple):
+    """The static channel facts of a block of reader rows."""
+
+    producer_ids: jax.Array      # [P] arange(P)
+    own: jax.Array               # [R, P] each reader's own channel
+    s_eff: jax.Array             # [R, P] two-tier staleness bound
+    in_pod: jax.Array | None     # [R, P] intra-pod channels
+    reader_pods: jax.Array | None  # [R] each reader's pod
+
+
+def reader_rows(cfg: ConsistencyConfig, reader_ids, P: int,
+                tiers: bool) -> Rows:
+    """`Rows` of readers ``reader_ids`` (``arange(P)`` in the simulator, a
+    worker shard's ids in the runtimes); the pod split only where it is
+    read (``tiers``: the wired view, telemetry's forced-refresh split)."""
+    producer_ids = jnp.arange(P, dtype=jnp.int32)
+    own = reader_ids[:, None] == producer_ids[None, :]
+    s_eff = staleness_bound_matrix(cfg, reader_ids, P)
+    in_pod = reader_pods = None
+    if tiers:
+        pods = pod_of(P, cfg.n_pods)                     # [P]
+        reader_pods = pods[reader_ids]
+        in_pod = reader_pods[:, None] == pods[None, :]
+    return Rows(producer_ids, own, s_eff, in_pod, reader_pods)
+
+
+def drop_dying(uring, cst, died, faulted: bool):
+    """Churn's drop policy: a worker dying this clock takes its in-flight
+    ring rows (and, wired, its unshipped ``acc``/``res`` and wire-ring
+    rows; faulted, its pending shipment and in-flight copy) with it — the
+    reference sequence loses those updates too."""
+    keep = ~died
+    uring = jnp.where(keep[None, :, None], uring, 0.0)
+    if cst is not None:
+        cst = dict(cst,
+                   acc=jnp.where(keep[:, None], cst["acc"], 0.0),
+                   res=jnp.where(keep[:, None], cst["res"], 0.0),
+                   xring=jnp.where(keep[None, :, None], cst["xring"], 0.0))
+        if faulted:
+            cst = wire.drop_pending(cst, keep)
+    return uring, cst
+
+
+def enforce(cfg: ConsistencyConfig, c, cview, norms, rows: Rows, *,
+            cst=None, faulted: bool = False, live_rows=None):
+    """Step 1, pre-read consistency enforcement (blocking fetches), given
+    the producers' suffix-aggregate inf-norms ``norms [W+1, P]``
+    (`kernels.ops.vap_suffix_norms`).
+
+    Returns ``(cview, forced, staleness, intransit_inf)``; the last is
+    the max over these rows' channels of the in-transit aggregate's
+    inf-norm at read time (the VAP-condition metric).
+    """
+    W = norms.shape[0] - 1
+    cview_pre = cview
+    if cfg.model == "bsp":
+        forced = cview < (c - 1)
+        cview = jnp.full_like(cview, c - 1)
+    elif cfg.model in ("ssp", "essp"):
+        # SSP condition: a read at clock c must include all updates of
+        # clocks <= c - s_eff - 1 (s intra-pod, s + s_xpod cross-pod,
+        # + agg_clocks - 1 under the comm substrate).  Lazy SSP
+        # refreshes the whole channel from the server (which holds
+        # everything through c-1) exactly when the bound trips — on a
+        # cross-pod channel that is the clock-gated reconciliation
+        # pull; ESSP rarely trips thanks to (two-tier) pushes.  Under
+        # the substrate a cross-pod refresh can only fetch what has
+        # *shipped* (through the last aggregation boundary).  Under a
+        # lossy wire the trigger deliberately stays at the unwidened
+        # bound: the refresh target is capped on `wire_tip`, so firing
+        # eagerly is always safe and keeps views as fresh as arrivals
+        # allow; only the *declared* contract (events / validate / model
+        # checker) carries the `+ retry_budget` widening.
+        s_eff = rows.s_eff
+        forced = cview < (c - s_eff - 1)
+        if cst is None:
+            cview = jnp.where(forced, c - 1, cview)
+        elif faulted:
+            # a faulted cross-pod refresh can only fetch what has
+            # actually *arrived*: wire_tip caps the shipped boundary
+            tgt = jnp.where(rows.in_pod, c - 1,
+                            jnp.minimum(
+                                comm.shipped_through(c, cfg.agg_clocks),
+                                cst["wire_tip"][None, :]))
+            cview = jnp.where(forced, tgt, cview)
+        else:
+            tgt = jnp.where(rows.in_pod, c - 1,
+                            comm.shipped_through(c, cfg.agg_clocks))
+            cview = jnp.where(forced, tgt, cview)
+    elif cfg.model == "vap":
+        cview, forced = enforce_vap(cfg, c, cview, norms, W)
+    else:  # async
+        forced = jnp.zeros_like(cview, dtype=bool)
+
+    if cfg.read_my_writes:
+        cview = jnp.where(rows.own, c - 1, cview)
+
+    if live_rows is not None:
+        # dead readers neither fetch nor advance: their cview rows
+        # freeze at death, which is what trips the bound (one forced
+        # burst) on their first read back — the catch-up mechanism.
+        forced = forced & live_rows[:, None]
+        cview = jnp.where(live_rows[:, None], cview, cview_pre)
+
+    staleness = cview - c
+    # The channel (r, q) has exactly the newest `c - 1 - cview[r,q]`
+    # clocks of producer q in transit, so its norm is one gather.
+    kcur = jnp.clip(c - 1 - cview, 0, W)
+    intransit_inf = jnp.max(norms[kcur, rows.producer_ids[None, :]])
+    return cview, forced, staleness, intransit_inf
+
+
+def read_views(base, uring, uclock, cview, rows: Rows, cst, zeros):
+    """Step 2, the readers' views ``[R, dcols]`` (Pallas on TPU).  Wired,
+    intra-pod producers read raw and cross-pod producers the shipped wire
+    ring, over a base folded per reader pod (`comm.reader_base`);
+    ``zeros [dcols]`` is the base of each tier's sum."""
+    if cst is None:
+        return ops.ring_view(base, uring, uclock, cview)
+    # masked-out channels see nothing (cview pinned below every clock)
+    cv_intra = jnp.where(rows.in_pod, cview, RING_EMPTY)
+    cv_xpod = jnp.where(rows.in_pod, RING_EMPTY, cview)
+    rb = comm.reader_base(base, cst["base_pod"], cst["xbase_pod"],
+                          rows.reader_pods)
+    return (rb + ops.ring_view(zeros, uring, uclock, cv_intra)
+            + ops.ring_view(zeros, cst["xring"], uclock, cv_xpod))
+
+
+def freeze_dead(u, local_new, local, live_rows):
+    """Churn: dead workers push nothing and their local state freezes; the
+    update still *runs* (vmap has no ragged lanes) but its output is
+    discarded, so survivor lanes are untouched.  Returns ``(u, local)``."""
+    if live_rows is None:
+        return u, local_new
+    R = live_rows.shape[0]
+    u = jnp.where(live_rows[:, None], u, 0.0)
+    local = jax.tree_util.tree_map(
+        lambda new, old: jnp.where(
+            live_rows.reshape((R,) + (1,) * (new.ndim - 1)), new, old),
+        local_new, local)
+    return u, local
+
+
+def commit(base, uring, uclock, cst, u, c, n_pods: int):
+    """Step 4, commit clock ``c``'s updates ``u`` to the ring slot of the
+    oldest clock, which first folds into the base.  Wired, the slot folds
+    per producer pod — raw into ``base_pod``, wire into ``xbase_pod``
+    (``base`` itself stays x0: reader bases assemble per pod in
+    `comm.reader_base`).  Returns ``(base, uring, uclock, cst, slot)``."""
+    slot = jnp.mod(c, uring.shape[0])
+    old_valid = uclock[slot] > RING_INVALID
+    if cst is None:
+        base = base + jnp.where(old_valid, 1.0, 0.0) * jnp.sum(
+            uring[slot], axis=0)
+    else:
+        w_old = jnp.where(old_valid, 1.0, 0.0)
+        cst = dict(cst, base_pod=cst["base_pod"]
+                   + w_old * comm.fold_pods(uring[slot], n_pods),
+                   xbase_pod=cst["xbase_pod"]
+                   + w_old * comm.fold_pods(cst["xring"][slot], n_pods))
+    return base, uring.at[slot].set(u), uclock.at[slot].set(c), cst, slot
+
+
+def ship(cfg: ConsistencyConfig, c, cst, u, slot, d: int, *, live_now=None,
+         faults: wire.WireFaults | None = None, full_rows=None):
+    """Step 4b, the comm substrate: accumulate ``u [P, dcols]`` and ship
+    one packed delta per producer on each aggregation boundary.
+
+    ``full_rows(delta)`` gives the full-width ``[P, d]`` rows the
+    threshold, scale and count read (the runtimes gather them over the
+    model axis; the pack itself is elementwise on the local columns).
+    Returns ``(cst, ship_floats [P])``; on the dense wire (``cst`` None)
+    every push-model producer ships ``d`` floats a clock.
+    """
+    P = u.shape[0]
+    if cst is None:
+        ship_floats = comm.dense_ship_floats(cfg.model, P, d)
+        if live_now is not None:
+            ship_floats = jnp.where(live_now, ship_floats, 0.0)
+        return cst, ship_floats
+    acc = cst["acc"] + u
+    delta = acc + cst["res"]
+    full = delta if full_rows is None else full_rows(delta)
+    thresh = comm.row_threshold(full, cfg.topk_frac)
+    scale = comm.quant_scale(full, cfg.quant)
+    wire_u, resid = ops.delta_pack(delta, thresh, scale, cfg.quant)
+    nnz = comm.selected_count(full, thresh)
+    send = comm.ship_now(c, cfg.agg_clocks)             # traced bool
+    if live_now is not None:
+        # dead producers hold their shipment: acc/res keep the unshipped
+        # mass (drain policy) and release it at the first boundary after
+        # rejoin — catching up through the wire ring.
+        send = send & live_now                          # [P]
+    if faults is not None:
+        # stop-and-wait ARQ: a busy producer (previous shipment unacked)
+        # skips the boundary — acc keeps accumulating and the skipped
+        # content rides the next shipment.
+        send = send & wire.idle(cst)                    # [P]
+    send_b = send if live_now is None and faults is None else send[:, None]
+    wire_u = jnp.where(send_b, wire_u, jnp.zeros_like(wire_u))
+    floats = comm.wire_floats(nnz, d, cfg.quant)
+    # Under faults the recycled ring slot clears: shipments enter the
+    # wire ring only when they *arrive*, via the seq-guarded fold inside
+    # wire_step (which also runs retransmits, give-up healing and this
+    # clock's instant arrivals, and charges every transmission — retries
+    # included — into ship_floats).
+    sent = dict(cst,
+                acc=jnp.where(send_b, jnp.zeros_like(acc), acc),
+                res=jnp.where(send_b, resid, cst["res"]),
+                xring=cst["xring"].at[slot].set(
+                    wire_u if faults is None else jnp.zeros_like(wire_u)))
+    if faults is None:
+        return sent, jnp.where(send, floats, jnp.zeros((P,), jnp.float32))
+    return wire.wire_step(sent, wire_u, floats, send, c, faults,
+                          live=live_now)
+
+
+def deliver(cfg: ConsistencyConfig, c, cview, sample, rows: Rows, *,
+            cst=None, faulted: bool = False, live_rows=None):
+    """Step 5, end-of-clock delivery (it affects reads at c+1).
+
+    ``sample()`` draws these rows of the delivery matrix
+    (`core.delays.delivery_matrix`); only the eager models call it.
+    Returns ``(cview, delivered)``.
+    """
+    if cfg.model == "bsp":
+        delivered = jnp.ones(cview.shape, bool)
+        if live_rows is not None:
+            # the barrier drains to live readers only; dead rows stay
+            # frozen (and catch up through the barrier on rejoin)
+            delivered = delivered & live_rows[:, None]
+            cview = jnp.where(live_rows[:, None],
+                              jnp.full_like(cview, c), cview)
+        else:
+            cview = jnp.full_like(cview, c)
+    elif cfg.model == "ssp":
+        delivered = jnp.zeros(cview.shape, bool)   # pull-based: no pushes
+    else:  # essp / async / vap: delay-driven eager delivery
+        delivered = sample()
+        if live_rows is not None:
+            # pushes to dead readers are lost (their caches are gone); the
+            # sampling itself is unmasked so survivor channels see the
+            # identical RNG draws with or without churn.
+            delivered = delivered & live_rows[:, None]
+        if cst is None:
+            cview = jnp.where(delivered, c, cview)
+        elif faulted:
+            # a cross-pod delivery carries the latest *arrived*
+            # shipment: the boundary target capped by wire_tip (updated
+            # by this clock's arrivals in wire_step)
+            tgt = jnp.where(rows.in_pod, c,
+                            jnp.minimum(
+                                comm.shipped_end(c, cfg.agg_clocks),
+                                cst["wire_tip"][None, :]))
+            cview = jnp.where(delivered, jnp.maximum(cview, tgt), cview)
+        else:
+            # a cross-pod delivery carries the latest *shipment*, so
+            # visibility advances only to the aggregation boundary
+            # (== c when agg_clocks == 1).
+            tgt = jnp.where(rows.in_pod, c,
+                            comm.shipped_end(c, cfg.agg_clocks))
+            cview = jnp.where(delivered, jnp.maximum(cview, tgt), cview)
+    return cview, delivered
+
+
+def table(base, uring, uclock, cst):
+    """The reference parameters: the folded base (and, wired, the per-pod
+    folds) plus every valid ring slot."""
+    if cst is not None:
+        base = base + jnp.sum(cst["base_pod"], axis=0)
+    return base + jnp.sum(
+        uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
 
 
 def simulate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
@@ -299,126 +582,37 @@ def simulate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
     uclock0 = jnp.full((W,), RING_EMPTY, jnp.int32)   # slot -> clock stored
     cview0 = jnp.full((P, P), -1, jnp.int32)      # everyone saw "clock -1"
     rng0 = jax.random.PRNGKey(seed)
-    # Two-tier staleness bound (hierarchical mode): `s` on intra-pod
-    # channels, `s + s_xpod` across pods (+ `agg_clocks - 1` under the
-    # substrate).  With n_pods=1 every channel is intra-pod and this is
-    # exactly `s` (integer ops — bit-identical).  Under a lossy wire the
-    # *trigger* deliberately stays at the unwidened bound: the refresh
-    # target is capped on `wire_tip`, so firing eagerly is always safe
-    # and keeps views as fresh as arrivals allow; only the *declared*
-    # contract (events / validate / model checker) carries the
-    # `+ retry_budget` widening for the lag an in-flight shipment can
-    # still impose.
-    s_eff = staleness_bound_matrix(cfg, jnp.arange(P), P)
-    if wired:
-        in_pod = same_pod_mask(P, G)                  # [P(r), P(q)]
-        reader_pods = pod_of(P, G)                    # [P]
-        zeros_d = jnp.zeros((d,), f32)
-        comm0 = comm.init_state(W, P, d, G)
-        if faulted:
-            comm0 = {**comm0, **wire.init_wire_state(P, d)}
-    if obs_enabled:
-        # channel-tier mask for the forced-refresh split (all-True when
-        # G == 1: every forced fetch is intra-pod)
-        in_pod_obs = in_pod if wired else same_pod_mask(P, G)
+    worker_ids = jnp.arange(P, dtype=jnp.int32)
+    rows = reader_rows(cfg, worker_ids, P, wired or obs_enabled)
+    zeros_d = jnp.zeros((d,), f32) if wired else None
+    comm0 = comm.init_state(W, P, d, G) if wired else None
+    if faulted:           # faults ride the substrate (wire.validate_faults)
+        comm0 = {**comm0, **wire.init_wire_state(P, d)}
+    oacc0 = obsm.device_init(P, obs.n_buckets) if obs_enabled else None
 
     vmapped_update = jax.vmap(app.worker_update,
                               in_axes=(0, 0, 0, None, 0))
-    worker_ids = jnp.arange(P, dtype=jnp.int32)
 
     def step(carry, c):
-        if obs_enabled:
-            *carry, oacc = carry
-        if wired:
-            (base, uring, uclock, cview, local, rng, cst) = carry
-        else:
-            base, uring, uclock, cview, local, rng = carry
+        base, uring, uclock, cview, local, rng, cst, oacc = carry
         rng, k_upd, k_net = jax.random.split(rng, 3)
 
+        live_now = rates = None
         if churned:
             live_now, died = churn_live(schedule, c)        # [P], [P]
             rates = churn_rates(cfg, schedule, P, c)
             if schedule.drop_inflight:
-                # drop policy: a worker dying this clock takes its
-                # in-flight (and, wired, unshipped) mass with it — the
-                # reference sequence loses those updates too.
-                keep = ~died
-                uring = jnp.where(keep[None, :, None], uring, 0.0)
-                if wired:
-                    cst = dict(cst,
-                               acc=jnp.where(keep[:, None], cst["acc"], 0.0),
-                               res=jnp.where(keep[:, None], cst["res"], 0.0),
-                               xring=jnp.where(keep[None, :, None],
-                                               cst["xring"], 0.0))
-                    if faulted:
-                        # the dying producer's pending shipment and
-                        # in-flight copy vanish with it too
-                        cst = wire.drop_pending(cst, keep)
-            cview_pre = cview
-        else:
-            rates = None
+                uring, cst = drop_dying(uring, cst, died, faulted)
 
         # Per-producer suffix-aggregate inf-norms of the newest k clocks
         # (kernels/ps_view.py): drives both VAP enforcement and the
-        # in-transit metric below.
+        # in-transit metric.
         norms = ops.vap_suffix_norms(uring, uclock, c)      # [W+1, P]
-
-        # --- 1. pre-read consistency enforcement (blocking fetches) -------
-        if cfg.model == "bsp":
-            forced = cview < (c - 1)
-            cview = jnp.full_like(cview, c - 1)
-        elif cfg.model in ("ssp", "essp"):
-            # SSP condition: a read at clock c must include all updates of
-            # clocks <= c - s_eff - 1 (s intra-pod, s + s_xpod cross-pod,
-            # + agg_clocks - 1 under the comm substrate).  Lazy SSP
-            # refreshes the whole channel from the server (which holds
-            # everything through c-1) exactly when the bound trips — on a
-            # cross-pod channel that is the clock-gated reconciliation
-            # pull; ESSP rarely trips thanks to (two-tier) pushes.  Under
-            # the substrate a cross-pod refresh can only fetch what has
-            # *shipped* (through the last aggregation boundary).
-            forced = cview < (c - s_eff - 1)
-            if wired and faulted:
-                # a faulted cross-pod refresh can only fetch what has
-                # actually *arrived*: wire_tip caps the shipped boundary
-                tgt = jnp.where(in_pod, c - 1,
-                                jnp.minimum(
-                                    comm.shipped_through(c, cfg.agg_clocks),
-                                    cst["wire_tip"][None, :]))
-                cview = jnp.where(forced, tgt, cview)
-            elif wired:
-                tgt = jnp.where(in_pod, c - 1,
-                                comm.shipped_through(c, cfg.agg_clocks))
-                cview = jnp.where(forced, tgt, cview)
-            else:
-                cview = jnp.where(forced, c - 1, cview)
-        elif cfg.model == "vap":
-            cview, forced = enforce_vap(cfg, c, cview, norms, W)
-        else:  # async
-            forced = jnp.zeros_like(cview, dtype=bool)
-
-        if cfg.read_my_writes:
-            eye = jnp.eye(P, dtype=bool)
-            cview = jnp.where(eye, c - 1, cview)
-
-        if churned:
-            # dead readers neither fetch nor advance: their cview rows
-            # freeze at death, which is what trips the bound (one forced
-            # burst) on their first read back — the catch-up mechanism.
-            forced = forced & live_now[:, None]
-            cview = jnp.where(live_now[:, None], cview, cview_pre)
-
-        staleness = cview - c                               # [P, P]
-
-        # VAP-condition metric: max over (reader, producer) channels of the
-        # inf-norm of the aggregated in-transit updates at read time.  The
-        # channel (r, q) has exactly the newest `c - 1 - cview[r,q]` clocks
-        # of producer q in transit, so its norm is one gather from `norms`.
-        kcur = jnp.clip(c - 1 - cview, 0, W)                # [P(r), P(q)]
-        intransit_inf = jnp.max(norms[kcur, jnp.arange(P)[None, :]])
+        cview, forced, staleness, intransit_inf = enforce(
+            cfg, c, cview, norms, rows, cst=cst, faulted=faulted,
+            live_rows=live_now)
 
         # --- 2. materialize views ----------------------------------------
-        # visibility mask x update ring -> per-reader views (Pallas on TPU).
         # NOTE on the VAP few-ulp drift PR 3 pinned: under a *multi-device*
         # compilation (sharded sweep, the runtimes) XLA's CPU backend
         # instruction-selects the scan body differently when the VAP
@@ -431,154 +625,24 @@ def simulate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         # bounded to a few ulp/value and is app-dependent (MF/LDA are
         # exactly stable).  `tests/test_sweep.py` pins it to a strict ulp
         # budget and asserts MF bit-identity.
-        if wired:
-            # Split the view per channel tier: intra-pod producers read
-            # raw, cross-pod producers read the shipped (compressed) wire
-            # ring; the folded base is assembled per reader pod
-            # (comm.reader_base).  Masked-out channels see nothing
-            # (cview pinned below every stored clock).
-            cv_intra = jnp.where(in_pod, cview, RING_EMPTY)
-            cv_xpod = jnp.where(in_pod, RING_EMPTY, cview)
-            rb = comm.reader_base(base, cst["base_pod"], cst["xbase_pod"],
-                                  reader_pods)
-            views = (rb + ops.ring_view(zeros_d, uring, uclock, cv_intra)
-                     + ops.ring_view(zeros_d, cst["xring"], uclock,
-                                     cv_xpod))
-        else:
-            views = ops.ring_view(base, uring, uclock, cview)
+        views = read_views(base, uring, uclock, cview, rows, cst, zeros_d)
 
         # --- 3. worker computation ----------------------------------------
         upd_keys = jax.random.split(k_upd, P)
         u, local_new = vmapped_update(views, local, worker_ids, c, upd_keys)
-        u = u.astype(f32)
-        if churned:
-            # dead workers push nothing and their local state freezes;
-            # the update still *runs* (vmap has no ragged lanes) but its
-            # output is discarded, so survivor lanes are untouched.
-            u = jnp.where(live_now[:, None], u, 0.0)
-            local = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(
-                    live_now.reshape((P,) + (1,) * (new.ndim - 1)),
-                    new, old),
-                local_new, local)
-        else:
-            local = local_new
+        u, local = freeze_dead(u.astype(f32), local_new, local, live_now)
 
         # --- 4. commit to server: fold oldest slot, write newest ----------
-        slot = jnp.mod(c, W)
-        old_valid = uclock[slot] > RING_INVALID
-        if wired:
-            # recycled slots fold per producer pod: raw into base_pod,
-            # wire into xbase_pod (base itself stays x0 — reader bases are
-            # assembled per pod in comm.reader_base).
-            w_old = jnp.where(old_valid, 1.0, 0.0)
-            cst = dict(cst,
-                       base_pod=cst["base_pod"]
-                       + w_old * comm.fold_pods(uring[slot], G),
-                       xbase_pod=cst["xbase_pod"]
-                       + w_old * comm.fold_pods(cst["xring"][slot], G))
-        else:
-            base = base + jnp.where(old_valid, 1.0, 0.0) * jnp.sum(uring[slot], axis=0)
-        uring = uring.at[slot].set(u)
-        uclock = uclock.at[slot].set(c)
-        if wired:
-            # --- 4b. comm substrate: accumulate, and ship on boundary ----
-            acc = cst["acc"] + u
-            delta = acc + cst["res"]                    # [P, d]
-            thresh = comm.row_threshold(delta, cfg.topk_frac)
-            scale = comm.quant_scale(delta, cfg.quant)
-            wire_u, resid = ops.delta_pack(delta, thresh, scale, cfg.quant)
-            nnz = comm.selected_count(delta, thresh)
-            ship = comm.ship_now(c, cfg.agg_clocks)     # traced bool
-            if churned:
-                # dead producers hold their shipment: acc/res keep the
-                # unshipped mass (drain policy) and release it at the
-                # first boundary after rejoin — catching up through the
-                # wire ring.
-                ship = ship & live_now                  # [P]
-            if faulted:
-                # stop-and-wait ARQ: a busy producer (previous shipment
-                # unacked) skips the boundary — acc keeps accumulating
-                # and the skipped content rides the next shipment.
-                ship = ship & wire.idle(cst)            # [P]
-            ship_b = ship[:, None] if (churned or faulted) else ship
-            wire_u = jnp.where(ship_b, wire_u, jnp.zeros_like(wire_u))
-            floats = comm.wire_floats(nnz, d, cfg.quant)
-            if faulted:
-                # the recycled ring slot clears; shipments enter the
-                # wire ring only when they *arrive*, via the
-                # seq-guarded fold inside wire_step (which also runs
-                # retransmits, give-up healing, and this clock's
-                # instant arrivals, and charges every transmission —
-                # retries included — into ship_floats).
-                cst = dict(cst,
-                           acc=jnp.where(ship_b, jnp.zeros_like(acc), acc),
-                           res=jnp.where(ship_b, resid, cst["res"]),
-                           xring=cst["xring"].at[slot].set(
-                               jnp.zeros_like(wire_u)))
-                cst, ship_floats = wire.wire_step(
-                    cst, wire_u, floats, ship, c, faults,
-                    live=live_now if churned else None)
-            else:
-                cst = dict(cst,
-                           acc=jnp.where(ship_b, jnp.zeros_like(acc), acc),
-                           res=jnp.where(ship_b, resid, cst["res"]),
-                           xring=cst["xring"].at[slot].set(wire_u))
-                ship_floats = jnp.where(
-                    ship, floats, jnp.zeros((P,), f32))
-        else:
-            ship_floats = comm.dense_ship_floats(cfg.model, P, d)
-            if churned:
-                ship_floats = jnp.where(live_now, ship_floats, 0.0)
-
-        # --- 5. end-of-clock delivery (affects reads at c+1) --------------
-        if cfg.model == "bsp":
-            delivered = jnp.ones((P, P), bool)
-            if churned:
-                # the barrier drains to live readers only; dead rows stay
-                # frozen (and catch up through the barrier on rejoin)
-                delivered = delivered & live_now[:, None]
-                cview = jnp.where(live_now[:, None],
-                                  jnp.full_like(cview, c), cview)
-            else:
-                cview = jnp.full_like(cview, c)
-        elif cfg.model == "ssp":
-            delivered = jnp.zeros((P, P), bool)   # pull-based: no pushes
-        else:  # essp / async / vap: delay-driven eager delivery
-            delivered = _delivery(k_net, cfg, P, rates)
-            if churned:
-                # pushes to dead readers are lost (their caches are gone);
-                # the sampling itself is unmasked so survivor channels see
-                # the identical RNG draws with or without churn.
-                delivered = delivered & live_now[:, None]
-            if wired and faulted:
-                # a cross-pod delivery carries the latest *arrived*
-                # shipment: the boundary target capped by wire_tip
-                # (updated by this clock's arrivals in wire_step above)
-                tgt = jnp.where(in_pod, c,
-                                jnp.minimum(
-                                    comm.shipped_end(c, cfg.agg_clocks),
-                                    cst["wire_tip"][None, :]))
-                cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                  cview)
-            elif wired:
-                # a cross-pod delivery carries the latest *shipment*, so
-                # visibility advances only to the aggregation boundary
-                # (== c when agg_clocks == 1).
-                tgt = jnp.where(in_pod, c,
-                                comm.shipped_end(c, cfg.agg_clocks))
-                cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                  cview)
-            else:
-                cview = jnp.where(delivered, c, cview)
+        base, uring, uclock, cst, slot = commit(base, uring, uclock, cst, u,
+                                                c, G)
+        cst, ship_floats = ship(cfg, c, cst, u, slot, d, live_now=live_now,
+                                faults=faults)
+        cview, delivered = deliver(
+            cfg, c, cview, lambda: delivery_matrix(k_net, cfg, P, rates),
+            rows, cst=cst, faulted=faulted, live_rows=live_now)
 
         # --- 6. record ------------------------------------------------------
-        if wired:
-            x_ref = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
-                uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
-        else:
-            x_ref = base + jnp.sum(
-                uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
+        x_ref = table(base, uring, uclock, cst)
         loss_ref = app.loss(x_ref, local)
         loss_view = app.loss(views[0], local)
         out = dict(loss_ref=loss_ref, loss_view=loss_view,
@@ -595,37 +659,16 @@ def simulate(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 oacc, staleness=staleness, forced=forced,
                 delivered=delivered, ship_floats=ship_floats,
                 live=out["live"], live_rows=out["live"],
-                in_pod=in_pod_obs)
-        new_carry = ((base, uring, uclock, cview, local, rng, cst)
-                     if wired else
-                     (base, uring, uclock, cview, local, rng))
-        if obs_enabled:
-            new_carry = (*new_carry, oacc)
-        return new_carry, out
+                in_pod=rows.in_pod)
+        return (base, uring, uclock, cview, local, rng, cst, oacc), out
 
-    carry0 = ((base0, uring0, uclock0, cview0, app.local0, rng0, comm0)
-              if wired else
-              (base0, uring0, uclock0, cview0, app.local0, rng0))
-    if obs_enabled:
-        carry0 = (*carry0, obsm.device_init(P, obs.n_buckets))
+    carry0 = (base0, uring0, uclock0, cview0, app.local0, rng0, comm0, oacc0)
     carryT, ys = jax.lax.scan(step, carry0,
                               jnp.arange(n_clocks, dtype=jnp.int32))
-    base, uring, uclock, _, local = carryT[0], carryT[1], carryT[2], \
-        carryT[3], carryT[4]
-    if wired:
-        cst = carryT[6]
-        x_final = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
-            uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
-    else:
-        x_final = base + jnp.sum(
-            uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
-    return Trace(
-        loss_ref=ys["loss_ref"], loss_view=ys["loss_view"],
-        staleness=ys["staleness"], forced=ys["forced"],
-        delivered=ys["delivered"], u_l2=ys["u_l2"],
-        intransit_inf=ys["intransit_inf"], ship_floats=ys["ship_floats"],
-        live=ys["live"], views0=ys.get("views0"), x_final=x_final,
-        locals_final=local, obs=carryT[-1] if obs_enabled else None)
+    base, uring, uclock, _, local, _, cst, oacc = carryT
+    return Trace(**{"views0": None, **ys},
+                 x_final=table(base, uring, uclock, cst),
+                 locals_final=local, obs=oacc)
 
 
 def simulate_jit(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,

@@ -1,7 +1,7 @@
 """VAP value-bound schedules and condition checking.
 
-The enforcement itself lives in `ps.simulate` (it needs the in-transit ring
-buffer); this module holds the schedule definitions and the post-hoc
+The enforcement itself lives in `ps.enforce_vap`, called by the clock step's
+shared `ps.enforce` (it needs the in-transit ring buffer); this module holds the schedule definitions and the post-hoc
 verification used by tests/benchmarks (paper eq. 1 and Theorem 1's
 `v_t = v0/sqrt(t)` requirement).
 """

@@ -59,12 +59,13 @@ can charge every device op of the clock to its stage; each collective
 runs under a nested ``exchange`` scope, which splits a stage's
 collective time from its compute.
 
-RNG and arithmetic mirror ``core.ps.simulate`` *exactly* (same key splits,
-same per-coordinate reduction orders), which is what makes the simulator an
-executable oracle: a seeded BSP run matches bit for bit, and the numeric
-knobs of `ConsistencyConfig` stay jit *arguments* (pytree data), so
-re-running with different staleness/push_prob/straggler knobs reuses the
-compiled program — one compile per config family, like ``core.sweep``.
+The step calls the simulator's policy functions (``core.ps.enforce``,
+``deliver``, ``ship`` and the rest) on its shard's reader rows and adds
+the data plane: shard geometry, collectives, RNG row slices; the
+bit-identity tests against ``core.ps.simulate`` check the rest.  The
+numeric knobs of `ConsistencyConfig` stay jit *arguments* (pytree data),
+so re-running with different staleness/push_prob/straggler knobs reuses
+the compiled program — one compile per config family, like ``core.sweep``.
 
 Mid-run state
 -------------
@@ -90,10 +91,11 @@ from ..comm import substrate as comm
 from ..comm import wire
 from ..core.consistency import ConsistencyConfig
 from ..core.delays import ChurnSchedule, churn_live, churn_rates, \
-    delivery_matrix, pod_of, staleness_bound_matrix
-from ..core.ps import PSApp, Trace, enforce_vap, fold_workers
+    delivery_matrix
+from ..core.ps import PSApp, Trace, commit, deliver, drop_dying, enforce, \
+    fold_workers, freeze_dead, read_views, reader_rows, ship, table
 from ..kernels import ops
-from ..kernels.ref import RING_EMPTY, RING_INVALID
+from ..kernels.ref import RING_EMPTY
 from ..launch.mesh import make_ps_mesh
 from ..obs import metrics as obsm
 
@@ -240,9 +242,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
     if cfg.n_pods > 1 and P % cfg.n_pods:
         raise ValueError(f"n_workers={P} must divide by n_pods={cfg.n_pods}")
     f32 = jnp.float32
-    # Static: route cross-pod shipment through the comm substrate — the
-    # same compressed state machine as core.ps.simulate's wired mode, so
-    # the oracle contract covers the compressed path too.
+    # Static: route cross-pod shipment through the comm substrate
+    # (`core.ps.ship`, the simulator's wired mode).
     wired = cfg.comm_active
     quant0, G = cfg.quant, cfg.n_pods
     obs_enabled = obsm.obs_on(obs)
@@ -255,16 +256,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         wire.validate_faults(faults, cfg, P, W)
     local_fixed = _local_fixed(app)
 
-    def body(cfg, clock0, base, uring, uclock, cview, local, rng,
-             *extra):
-        _i = 0
-        cst = flt = sched = None
-        if wired:
-            cst, _i = extra[_i], _i + 1
-        if faulted:
-            flt, _i = extra[_i], _i + 1
-        if churned:
-            sched = extra[_i]
+    def body(cfg, clock0, base, uring, uclock, cview, local, rng, cst, flt,
+             sched):
         # local shards: base [dl], uring [W, P, dl], uclock [W] (replicated),
         # cview [Pl, P], local leaves [Pl, ...], rng/clock0 replicated;
         # comm state (wired only): acc/res [P, dl], xring [W, P, dl],
@@ -274,28 +267,8 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
         mi = jax.lax.axis_index("model")
         rows0 = (di * Pl).astype(jnp.int32)
         worker_ids = rows0 + jnp.arange(Pl, dtype=jnp.int32)
-        producer_ids = jnp.arange(P, dtype=jnp.int32)
-        eye_l = worker_ids[:, None] == producer_ids[None, :]   # local eye rows
-        # Two-tier staleness bound on the local reader rows (`s` intra-pod,
-        # `s + s_xpod` cross-pod, `+ agg_clocks - 1` under the substrate;
-        # one-tier and exactly `s` when n_pods=1).  The lossy-wire trigger
-        # stays *unwidened* — refresh targets are capped on `wire_tip`, so
-        # eager firing is safe; only the declared contract carries the
-        # `+ retry_budget` widening (oracle mirror).
-        s_eff = staleness_bound_matrix(cfg, worker_ids, P)       # [Pl, P]
-        if wired:
-            pods_all = pod_of(P, G)                            # [P]
-            reader_pods = pods_all[worker_ids]                 # [Pl]
-            in_pod = reader_pods[:, None] == pods_all[None, :]  # [Pl, P]
-            zeros_dl = jnp.zeros((dl,), f32)
-        if obs_enabled:
-            # channel-tier mask on the local reader rows for the
-            # forced-refresh split (all-True when G == 1)
-            if wired:
-                in_pod_obs = in_pod
-            else:
-                pods_o = pod_of(P, G)
-                in_pod_obs = pods_o[worker_ids][:, None] == pods_o[None, :]
+        rows = reader_rows(cfg, worker_ids, P, wired or obs_enabled)
+        zeros_dl = jnp.zeros((dl,), f32) if wired else None
 
         vmapped_update = jax.vmap(app.worker_update,
                                   in_axes=(0, 0, 0, None, 0))
@@ -331,114 +304,46 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
             return [fold_workers(sums[i]) / (n * P)
                     for i, n in enumerate(counts)]
 
+        def full_rows(delta):
+            # thresholds/scales/counts read the *gathered* full rows (the
+            # simulator's [P, d] operand, so its sort's bits)
+            with exchange():
+                return jax.lax.all_gather(delta, "model", axis=1,
+                                          tiled=True)[:, :d]
+
         local_in = local
 
         def step(carry, c):
-            if obs_enabled:
-                *carry, oacc = carry
-            if wired:
-                base, uring, uclock, cview, local, rng, cst = carry
-            else:
-                base, uring, uclock, cview, local, rng = carry
+            base, uring, uclock, cview, local, rng, cst, oacc = carry
             if local_fixed:
                 local = local_in
             with jax.named_scope("psrun.enforce"):
                 rng, k_upd, k_net = jax.random.split(rng, 3)
 
+                live_now = live_l = rates = None
                 if churned:
                     live_now, died = churn_live(sched, c)     # [P], [P]
                     live_l = jax.lax.dynamic_slice_in_dim(
                         live_now, rows0, Pl)                  # local reader rows
                     rates = churn_rates(cfg, sched, P, c)
                     if sched.drop_inflight:
-                        # drop policy: mirror the oracle — a dying worker's
-                        # in-flight ring rows (and unshipped comm rows) zero
-                        # out the clock it dies.
-                        keep = ~died
-                        uring = jnp.where(keep[None, :, None], uring, 0.0)
-                        if wired:
-                            cst = dict(cst,
-                                       acc=jnp.where(keep[:, None],
-                                                     cst["acc"], 0.0),
-                                       res=jnp.where(keep[:, None],
-                                                     cst["res"], 0.0),
-                                       xring=jnp.where(keep[None, :, None],
-                                                       cst["xring"], 0.0))
-                        if faulted:
-                            # a dying producer's unacked shipment and lane
-                            # copies vanish with it (oracle mirror)
-                            cst = wire.drop_pending(cst, keep)
-                    cview_pre = cview
-                else:
-                    rates = None
+                        uring, cst = drop_dying(uring, cst, died, faulted)
 
                 # global per-producer suffix-aggregate inf-norms: local block
                 # norms, max-reduced over the owning shards.
                 norms = ops.vap_suffix_norms(uring, uclock, c)
                 with exchange():
                     norms = jax.lax.pmax(norms, "model")           # [W+1, P]
-
-                # --- 1. pre-read consistency enforcement (blocking fetches) --
-                if cfg.model == "bsp":
-                    forced = cview < (c - 1)
-                    cview = jnp.full_like(cview, c - 1)
-                elif cfg.model in ("ssp", "essp"):
-                    forced = cview < (c - s_eff - 1)
-                    if wired and faulted:
-                        # a faulted cross-pod refresh can only fetch what has
-                        # actually *arrived*: wire_tip caps the shipped
-                        # boundary (oracle mirror)
-                        tgt = jnp.where(in_pod, c - 1,
-                                        jnp.minimum(
-                                            comm.shipped_through(
-                                                c, cfg.agg_clocks),
-                                            cst["wire_tip"][None, :]))
-                        cview = jnp.where(forced, tgt, cview)
-                    elif wired:
-                        # cross-pod refreshes fetch what has *shipped* (through
-                        # the last aggregation boundary), mirroring the oracle
-                        tgt = jnp.where(in_pod, c - 1,
-                                        comm.shipped_through(c, cfg.agg_clocks))
-                        cview = jnp.where(forced, tgt, cview)
-                    else:
-                        cview = jnp.where(forced, c - 1, cview)
-                elif cfg.model == "vap":
-                    cview, forced = enforce_vap(cfg, c, cview, norms, W)
-                else:  # async
-                    forced = jnp.zeros_like(cview, dtype=bool)
-
-                if cfg.read_my_writes:
-                    cview = jnp.where(eye_l, c - 1, cview)
-
-                if churned:
-                    # dead readers neither fetch nor advance (oracle mirror)
-                    forced = forced & live_l[:, None]
-                    cview = jnp.where(live_l[:, None], cview, cview_pre)
-
-                staleness = cview - c                              # [Pl, P]
-
-                kcur = jnp.clip(c - 1 - cview, 0, W)               # [Pl, P]
-                intransit_inf = jnp.max(norms[kcur, producer_ids[None, :]])
+                cview, forced, staleness, intransit_inf = enforce(
+                    cfg, c, cview, norms, rows, cst=cst, faulted=faulted,
+                    live_rows=live_l)
                 with exchange():
                     intransit_inf = jax.lax.pmax(intransit_inf, worker_axes)
 
             with jax.named_scope("psrun.view"):
                 # --- 2. materialize views: shard-local, then assemble --------
-                if wired:
-                    # intra-pod producers read raw, cross-pod producers read
-                    # the shipped wire ring; folded bases assemble per reader
-                    # pod — the same three-term sum as the oracle.
-                    cv_intra = jnp.where(in_pod, cview, RING_EMPTY)
-                    cv_xpod = jnp.where(in_pod, RING_EMPTY, cview)
-                    rb = comm.reader_base(base, cst["base_pod"],
-                                          cst["xbase_pod"], reader_pods)
-                    views_l = (rb
-                               + ops.ring_view(zeros_dl, uring, uclock,
-                                               cv_intra)
-                               + ops.ring_view(zeros_dl, cst["xring"], uclock,
-                                               cv_xpod))              # [Pl, dl]
-                else:
-                    views_l = ops.ring_view(base, uring, uclock, cview)
+                views_l = read_views(base, uring, uclock, cview, rows, cst,
+                                     zeros_dl)                     # [Pl, dl]
                 with exchange():
                     views = jax.lax.all_gather(views_l, "model", axis=1,
                                                tiled=True)[:, :d]    # [Pl, d]
@@ -449,19 +354,11 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                     jax.random.split(k_upd, P), rows0, Pl)
                 u_l, local_new = vmapped_update(views, local, worker_ids, c,
                                                 upd_keys)
-                u_l = u_l.astype(f32)                              # [Pl, d]
-                if churned:
-                    # mask dead workers' pushes BEFORE the all-gather so the
-                    # gathered [P, d] (and u_l2 on it) matches the oracle's
-                    # masked operand bit for bit; freeze their local state.
-                    u_l = jnp.where(live_l[:, None], u_l, 0.0)
-                    local = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(
-                            live_l.reshape((Pl,) + (1,) * (new.ndim - 1)),
-                            new, old),
-                        local_new, local)
-                else:
-                    local = local_new
+                # dead workers' pushes are masked BEFORE the all-gather, so
+                # the gathered [P, d] (and u_l2 on it) is the simulator's
+                # masked operand bit for bit
+                u_l, local = freeze_dead(u_l.astype(f32), local_new, local,
+                                         live_l)                   # [Pl, d]
 
             with jax.named_scope("psrun.push"):
                 # --- 4. push to owning shards; fold oldest slot --------------
@@ -472,128 +369,26 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                 with exchange():
                     u_all = jax.lax.all_gather(u_l, worker_axes, axis=0,
                                                tiled=True)
-                # norm on the gathered [P, d] — the oracle's operand shape, so
-                # XLA emits the same reduction and the floats match bit-for-bit
+                # norm on the gathered [P, d], the simulator's operand: XLA
+                # emits the same reduction and the floats match
                 u_l2 = jnp.linalg.norm(u_all, axis=-1)
                 u_all = jnp.pad(u_all, ((0, 0), (0, dpad - d)))
                 u_blk = jax.lax.dynamic_slice(u_all, (0, mi * dl), (P, dl))
-                slot = jnp.mod(c, W)
-                old_valid = uclock[slot] > RING_INVALID
-                if wired:
-                    w_old = jnp.where(old_valid, 1.0, 0.0)
-                    cst = dict(cst,
-                               base_pod=cst["base_pod"]
-                               + w_old * comm.fold_pods(uring[slot], G),
-                               xbase_pod=cst["xbase_pod"]
-                               + w_old * comm.fold_pods(cst["xring"][slot], G))
-                else:
-                    base = base + jnp.where(old_valid, 1.0, 0.0) * jnp.sum(
-                        uring[slot], axis=0)
-                uring = uring.at[slot].set(u_blk)
-                uclock = uclock.at[slot].set(c)
-                if wired:
-                    # --- 4b. comm substrate: accumulate; ship on boundary ----
-                    # thresholds/scales/counts come from the *gathered* full
-                    # rows (bit-identical to the oracle's [P, d] sort); the
-                    # pack itself is elementwise on the local shard.
-                    acc = cst["acc"] + u_blk
-                    delta = acc + cst["res"]                     # [P, dl]
-                    with exchange():
-                        delta_full = jax.lax.all_gather(
-                            delta, "model", axis=1, tiled=True)[:, :d]
-                    thresh = comm.row_threshold(delta_full, cfg.topk_frac)
-                    scale = comm.quant_scale(delta_full, cfg.quant)
-                    wire_u, resid = ops.delta_pack(delta, thresh, scale,
-                                                   cfg.quant)
-                    nnz = comm.selected_count(delta_full, thresh)
-                    ship = comm.ship_now(c, cfg.agg_clocks)
-                    if churned:
-                        # dead producers hold their shipment (drain policy:
-                        # acc/res keep the mass until the first boundary
-                        # after rejoin) — oracle mirror.
-                        ship = ship & live_now                 # [P]
-                    if faulted:
-                        # stop-and-wait ARQ: a busy producer (previous
-                        # shipment unacked) skips the boundary — acc keeps
-                        # accumulating and the skipped content rides the
-                        # next shipment (oracle mirror).
-                        ship = ship & wire.idle(cst)           # [P]
-                    ship_b = ship[:, None] if (churned or faulted) else ship
-                    wire_u = jnp.where(ship_b, wire_u, jnp.zeros_like(wire_u))
-                    floats = comm.wire_floats(nnz, d, cfg.quant)
-                    if faulted:
-                        # shipments enter the wire ring only when they
-                        # *arrive*, via the seq-guarded fold in wire_step
-                        # (which also runs retransmits, give-up healing and
-                        # instant arrivals, and charges every transmission —
-                        # retries included — into ship_floats).
-                        cst = dict(cst,
-                                   acc=jnp.where(ship_b, jnp.zeros_like(acc),
-                                                 acc),
-                                   res=jnp.where(ship_b, resid, cst["res"]),
-                                   xring=cst["xring"].at[slot].set(
-                                       jnp.zeros_like(wire_u)))
-                        cst, ship_floats = wire.wire_step(
-                            cst, wire_u, floats, ship, c, flt,
-                            live=live_now if churned else None)
-                    else:
-                        cst = dict(cst,
-                                   acc=jnp.where(ship_b, jnp.zeros_like(acc),
-                                                 acc),
-                                   res=jnp.where(ship_b, resid, cst["res"]),
-                                   xring=cst["xring"].at[slot].set(wire_u))
-                        ship_floats = jnp.where(
-                            ship, floats, jnp.zeros((P,), f32))
-                else:
-                    ship_floats = comm.dense_ship_floats(cfg.model, P, d)
-                    if churned:
-                        ship_floats = jnp.where(live_now, ship_floats, 0.0)
-
-                # --- 5. end-of-clock delivery (affects reads at c+1) ---------
-                if cfg.model == "bsp":
-                    delivered = jnp.ones((Pl, P), bool)
-                    if churned:
-                        delivered = delivered & live_l[:, None]
-                        cview = jnp.where(live_l[:, None],
-                                          jnp.full_like(cview, c), cview)
-                    else:
-                        cview = jnp.full_like(cview, c)
-                elif cfg.model == "ssp":
-                    delivered = jnp.zeros((Pl, P), bool)
-                else:  # essp / async / vap: delay-driven eager delivery
-                    delivered = jax.lax.dynamic_slice_in_dim(
-                        delivery_matrix(k_net, cfg, P, rates), rows0, Pl)
-                    if churned:
-                        delivered = delivered & live_l[:, None]
-                    if wired and faulted:
-                        # deliveries carry the latest *arrived* shipment:
-                        # boundary target capped by wire_tip (oracle mirror)
-                        tgt = jnp.where(in_pod, c,
-                                        jnp.minimum(
-                                            comm.shipped_end(
-                                                c, cfg.agg_clocks),
-                                            cst["wire_tip"][None, :]))
-                        cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                          cview)
-                    elif wired:
-                        tgt = jnp.where(in_pod, c,
-                                        comm.shipped_end(c, cfg.agg_clocks))
-                        cview = jnp.where(delivered, jnp.maximum(cview, tgt),
-                                          cview)
-                    else:
-                        cview = jnp.where(delivered, c, cview)
+                base, uring, uclock, cst, slot = commit(
+                    base, uring, uclock, cst, u_blk, c, G)
+                cst, ship_floats = ship(cfg, c, cst, u_blk, slot, d,
+                                        live_now=live_now, faults=flt,
+                                        full_rows=full_rows)
+                cview, delivered = deliver(
+                    cfg, c, cview,
+                    lambda: jax.lax.dynamic_slice_in_dim(
+                        delivery_matrix(k_net, cfg, P, rates), rows0, Pl),
+                    rows, cst=cst, faulted=faulted, live_rows=live_l)
 
             with jax.named_scope("psrun.record"):
                 # --- 6. record: the table and reader 0's view, the losses
                 # per worker shard (`losses`) ------------------------------
-                if wired:
-                    x_ref = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
-                        uring * (uclock[:, None, None] > RING_INVALID),
-                        axis=(0, 1))
-                else:
-                    x_ref = base + jnp.sum(
-                        uring * (uclock[:, None, None] > RING_INVALID),
-                        axis=(0, 1))
+                x_ref = table(base, uring, uclock, cst)
                 # reader 0's view, sent from the shard that holds it to every
                 # shard: the others add zeros (exact)
                 sent = jnp.where(di == 0, views[0], 0.0)
@@ -620,42 +415,28 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                         live=out["live"],
                         live_rows=live_l if churned
                         else jnp.ones((Pl,), bool),
-                        in_pod=in_pod_obs)
+                        in_pod=rows.in_pod)
             if local_fixed:
                 local = None
-            new_carry = ((base, uring, uclock, cview, local, rng, cst)
-                         if wired else
-                         (base, uring, uclock, cview, local, rng))
-            if obs_enabled:
-                new_carry = (*new_carry, oacc)
-            return new_carry, out
+            return (base, uring, uclock, cview, local, rng, cst, oacc), out
 
         clocks = clock0 + jnp.arange(n_clocks, dtype=jnp.int32)
         if local_fixed:
             local = None
-        carry0 = ((base, uring, uclock, cview, local, rng, cst)
-                  if wired else
-                  (base, uring, uclock, cview, local, rng))
-        if obs_enabled:
-            carry0 = (*carry0, obsm.device_init(P, obs.n_buckets))
-        carryT, ys = jax.lax.scan(step, carry0, clocks)
-        base, uring, uclock, cview, local, rng = carryT[:6]
-        if wired:
-            cst = carryT[6]
-            x_final = (base + jnp.sum(cst["base_pod"], axis=0)) + jnp.sum(
-                uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
-        else:
-            x_final = base + jnp.sum(
-                uring * (uclock[:, None, None] > RING_INVALID), axis=(0, 1))
+        oacc = obsm.device_init(P, obs.n_buckets) if obs_enabled else None
+        carryT, ys = jax.lax.scan(
+            step, (base, uring, uclock, cview, local, rng, cst, oacc),
+            clocks)
+        base, uring, uclock, cview, local, rng, cst, oacc = carryT
+        x_final = table(base, uring, uclock, cst)
         state = dict(clock=clock0 + n_clocks, base=base,
                      uring=uring, uclock=uclock, cview=cview,
-                     local=local, rng=rng,
-                     comm=cst if wired else None)
+                     local=local, rng=rng, comm=cst)
         ret = {"ys": ys, "x_final": x_final, "state": state}
         if obs_enabled:
             # merge the per-shard accumulators: one psum/pmax per reduced
             # leaf for the whole run (replicated leaves pass through)
-            ret["obs"] = obsm.device_reduce(carryT[-1], worker_axes)
+            ret["obs"] = obsm.device_reduce(oacc, worker_axes)
         return ret
 
     local_spec = jax.tree_util.tree_map(lambda _: P_(worker_axes), app.local0)
@@ -685,18 +466,12 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
                        cview=P_(worker_axes, None),
                        local=None if local_fixed else local_spec,
                        rng=P_(), comm=comm_specs)
-    in_specs = [P_(), P_(), P_("model"), P_(None, None, "model"), P_(),
-                P_(worker_axes, None), local_spec, P_()]
-    if wired:
-        in_specs.append(comm_specs)
-    if faulted:
-        # fault masks are replicated: every shard needs all P producers'
-        # fault rows (like the churn schedule)
-        in_specs.append(jax.tree_util.tree_map(lambda _: P_(), faults))
-    if churned:
-        # the schedule is replicated: every shard reads the full per-clock
-        # liveness rows (it needs producer liveness for all P)
-        in_specs.append(jax.tree_util.tree_map(lambda _: P_(), schedule))
+    # the fault masks and the churn schedule are replicated: every shard
+    # reads all P producers' rows of them
+    in_specs = (P_(), P_(), P_("model"), P_(None, None, "model"), P_(),
+                P_(worker_axes, None), local_spec, P_(), comm_specs,
+                jax.tree_util.tree_map(lambda _: P_(), faults),
+                jax.tree_util.tree_map(lambda _: P_(), schedule))
     out_specs = {"ys": ys_specs, "x_final": P_("model"),
                  "state": state_specs}
     if obs_enabled:
@@ -705,30 +480,17 @@ def make_run_fn(app: PSApp, cfg: ConsistencyConfig, n_clocks: int,
             lambda _: P_(), obsm.device_init(P, obs.n_buckets))
     sharded = jax.shard_map(
         body, mesh=mesh,
-        in_specs=tuple(in_specs),
+        in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False)
 
     def run(state: PSState, cfg, sched, flt):
-        args = (cfg, state.clock, state.base, state.uring,
-                state.uclock, state.cview, state.local, state.rng)
-        if wired:
-            args += (state.comm,)
-        if faulted:
-            args += (flt,)
-        if churned:
-            args += (sched,)
-        out = sharded(*args)
-        ys = out["ys"]
-        trace = Trace(loss_ref=ys["loss_ref"], loss_view=ys["loss_view"],
-                      staleness=ys["staleness"], forced=ys["forced"],
-                      delivered=ys["delivered"], u_l2=ys["u_l2"],
-                      intransit_inf=ys["intransit_inf"],
-                      ship_floats=ys["ship_floats"], live=ys["live"],
-                      views0=ys.get("views0"),
+        out = sharded(cfg, state.clock, state.base, state.uring,
+                      state.uclock, state.cview, state.local, state.rng,
+                      state.comm, flt, sched)
+        trace = Trace(**{"views0": None, **out["ys"]},
                       x_final=out["x_final"][:d],
-                      locals_final=out["state"]["local"],
-                      obs=out.get("obs"))
+                      locals_final=out["state"]["local"], obs=out.get("obs"))
         return trace, PSState(**out["state"])
 
     jit_run = jax.jit(run)

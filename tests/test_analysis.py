@@ -132,16 +132,18 @@ def test_bound_extraction_matches_declared_algebra():
 @pytest.mark.parametrize(("producer", "path"), PRODUCERS,
                          ids=[p for p, _ in PRODUCERS])
 def test_model_check_verifies_producer(producer, path):
-    """The exhaustive small-config grid finds no contract violation, and
-    the pods runtime is recognized as delegating to the psrun body."""
+    """The exhaustive small-config grid finds no contract violation in the
+    policy of ``core/ps.py``, the psrun runtime is recognized as calling
+    it, and the pods runtime as running the psrun body."""
     bm = extract_bound_model(os.path.join(SRC, "core", "delays.py"))
     enf = extract_enforcement(path, producer)
     assert enf.trigger_offset == 1
     assert enf.refresh_lag == 1
     assert enf.xpod_refresh_shipped
     assert enf.delivery_shipped
-    if producer == "pods/runtime.py":
-        assert enf.delegate == "psrun/runtime.py"
+    assert enf.delegate == {"core/ps.py": None,
+                            "psrun/runtime.py": "core/ps.py",
+                            "pods/runtime.py": "psrun/runtime.py"}[producer]
     ces = model_check(bm, enf)
     assert ces == [], "\n".join(str(c) for c in ces)
 
@@ -155,8 +157,8 @@ def test_model_check_detects_widening_mutant():
     mutant = src.replace("(cfg.agg_clocks - 1)", "(cfg.agg_clocks - 2)")
     assert mutant != src, "widening expression not found to mutate"
     bm = extract_bound_model_from_source(mutant)
-    enf = extract_enforcement(os.path.join(SRC, "psrun", "runtime.py"),
-                              "psrun/runtime.py")
+    enf = extract_enforcement(os.path.join(SRC, "core", "ps.py"),
+                              "core/ps.py")
     ces = model_check(bm, enf)
     assert ces, "mutant bound not detected"
     # the faulted cross-pod channel shares the agg widening, so the
@@ -178,8 +180,8 @@ def test_model_check_detects_retry_budget_mutant():
     mutant = src.replace("+ retry_budget", "+ (retry_budget - 1)")
     assert mutant != src, "retry_budget widening not found to mutate"
     bm = extract_bound_model_from_source(mutant)
-    enf = extract_enforcement(os.path.join(SRC, "psrun", "runtime.py"),
-                              "psrun/runtime.py")
+    enf = extract_enforcement(os.path.join(SRC, "core", "ps.py"),
+                              "core/ps.py")
     ces = model_check(bm, enf)
     assert ces, "retry_budget mutant not detected"
     # the same mutated expression also evaluates at retry_budget=0 on
@@ -203,34 +205,54 @@ def test_model_check_detects_retry_budget_mutant():
 
 
 def test_faulted_extraction_requires_wire_tip_caps():
-    """Both producers cap faulted refresh/delivery on ``wire_tip``; a
-    producer that drops either cap must fail extraction loudly (the cap
-    guards against reading unarrived ring content, which the staleness
-    lag invariant alone cannot observe)."""
+    """The shared policy caps faulted refresh/delivery on ``wire_tip``,
+    and the runtime that calls it inherits the caps; a policy that drops
+    either cap must fail extraction loudly (the cap guards against
+    reading unarrived ring content, which the staleness lag invariant
+    alone cannot observe)."""
     from repro.analysis import extract_enforcement_from_source
 
-    for producer in ("core/ps.py", "psrun/runtime.py"):
-        path = os.path.join(SRC, *producer.split("/"))
-        src = open(path, encoding="utf-8").read()
-        enf = extract_enforcement_from_source(src, producer)
-        assert enf.xpod_refresh_capped and enf.delivery_capped
-        uncapped = src.replace('cst["wire_tip"]', 'cst["pend_clock"]')
-        assert uncapped != src
-        with pytest.raises(ExtractionError):
-            extract_enforcement_from_source(uncapped, producer)
+    enf = extract_enforcement(os.path.join(SRC, "psrun", "runtime.py"),
+                              "psrun/runtime.py")
+    assert enf.xpod_refresh_capped and enf.delivery_capped
+    src = open(os.path.join(SRC, "core", "ps.py"), encoding="utf-8").read()
+    enf = extract_enforcement_from_source(src, "core/ps.py")
+    assert enf.xpod_refresh_capped and enf.delivery_capped
+    uncapped = src.replace('cst["wire_tip"]', 'cst["pend_clock"]')
+    assert uncapped != src
+    with pytest.raises(ExtractionError):
+        extract_enforcement_from_source(uncapped, "core/ps.py")
 
 
 def test_extraction_is_brittle_on_drift():
     """If a producer's enforcement pattern drifts, extraction fails loudly
     rather than silently verifying stale algebra."""
-    src = open(os.path.join(SRC, "psrun", "runtime.py"),
-               encoding="utf-8").read()
+    src = open(os.path.join(SRC, "core", "ps.py"), encoding="utf-8").read()
     drifted = src.replace("forced = cview < (c - s_eff - 1)",
                           "forced = cview <= (c - s_eff - 1)")
     assert drifted != src
     from repro.analysis import extract_enforcement_from_source
     with pytest.raises(ExtractionError):
-        extract_enforcement_from_source(drifted, "psrun/runtime.py")
+        extract_enforcement_from_source(drifted, "core/ps.py")
+
+
+def test_extraction_refuses_a_trigger_beside_the_shared_call():
+    """The runtime delegates its enforcement to ``core/ps.py``; a runtime
+    that also fires a trigger of its own beside the shared call fails
+    extraction, since the checker would then verify code the runtime
+    does not run alone."""
+    src = open(os.path.join(SRC, "psrun", "runtime.py"),
+               encoding="utf-8").read()
+    call = "cview, forced, staleness, intransit_inf = enforce("
+    assert src.count(call) == 1
+    indent = src.split(call)[0].rsplit("\n", 1)[1]
+    pasted = src.replace(
+        call, f"forced = cview < (c - s_eff - 1)\n{indent}{call}")
+    from repro.analysis import extract_enforcement_from_source
+    assert extract_enforcement_from_source(
+        src, "psrun/runtime.py").delegate == "core/ps.py"
+    with pytest.raises(ExtractionError, match="re-implements"):
+        extract_enforcement_from_source(pasted, "psrun/runtime.py")
 
 
 def test_model_check_covers_churn_outages():
